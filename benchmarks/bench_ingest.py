@@ -231,11 +231,14 @@ def run(quick: bool = False) -> None:
         f"quantized per-device resident bytes {ratio:.3f}x float32 at "
         f"E=8 exceeds the {QUANT_BYTES_CEILING}x acceptance ceiling")
     emit("ingest/engine-E8-bytes-f32", r["f32_bytes_per_device"],
-         f"n={r['n']};q={r['q']}", unit="bytes")
+         f"n={r['n']};q={r['q']}", unit="bytes",
+         config={"backend": r["backend"]})
     emit("ingest/engine-E8-bytes-u16", r["u16_bytes_per_device"],
-         f"parity_queries={r['parity_queries']}", unit="bytes")
+         f"parity_queries={r['parity_queries']}", unit="bytes",
+         config={"backend": r["backend"]})
     emit("ingest/engine-E8-quant-bytes-ratio", ratio,
-         f"ceiling={QUANT_BYTES_CEILING}", unit="bytes_ratio")
+         f"ceiling={QUANT_BYTES_CEILING}", unit="bytes_ratio",
+         config={"backend": r["backend"]})
 
     if not quick:
         _index_build_point(GRID_FULL, DISTRICT_FULL, "2.5e5")

@@ -97,7 +97,8 @@ def run(quick: bool = False) -> None:
         emit(f"oracle-sharding/{name}",
              r[name]["coll_mb"] * 1e3,  # KB collectives per 4k queries
              f"arg_mb_per_dev={r[name]['arg_mb']:.2f};n={r['n']};q={r['q']}"
-             f";col2=coll_kb_per_4k_queries", unit="bytes")
+             f";col2=coll_kb_per_4k_queries", unit="bytes",
+             config={"backend": r["backend"]})
     run_engine_sweep(quick=quick)
 
 
@@ -124,7 +125,8 @@ def run_engine_sweep(quick: bool = False) -> None:
                  sec / int(b) * 1e6,
                  f"qps={int(b) / sec:,.0f}"
                  f";table_bytes_per_dev={r['per_device_table_bytes']}"
-                 f";district_frac={dfrac:.3f};resident_frac={rfrac:.3f}")
+                 f";district_frac={dfrac:.3f};resident_frac={rfrac:.3f}",
+                 config={"backend": r["backend"]})
         for b, sec in r["sweep_border"].items():
             emit(f"oracle-sharding/engine-border-E{ndev}-b{b}",
                  sec / int(b) * 1e6,
@@ -132,7 +134,8 @@ def run_engine_sweep(quick: bool = False) -> None:
                  f";border_bytes_per_dev={r['border_table_bytes_per_device']}"
                  f";district_frac={dfrac:.3f}"
                  f";border_resident_frac={bfrac:.3f}"
-                 f";n={r['n']};q={r['q']}")
+                 f";n={r['n']};q={r['q']}",
+                 config={"backend": r["backend"]})
 
 
 if __name__ == "__main__":

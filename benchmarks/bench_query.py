@@ -160,21 +160,25 @@ def run_sharded() -> None:
     bfrac = r["border_resident_bytes"] / r["replicated_table_bytes"]
     for b, sec in r["sweep"].items():
         emit(f"engine/sharded-{b}", sec / int(b) * 1e6,
-             f"qps={int(b) / sec:,.0f};devices={r['devices']}")
+             f"qps={int(b) / sec:,.0f};devices={r['devices']}",
+             config={"backend": r["backend"]})
     for b, sec in r["sweep_border"].items():
         emit(f"engine/border-sharded-{b}", sec / int(b) * 1e6,
-             f"qps={int(b) / sec:,.0f};devices={r['devices']}")
+             f"qps={int(b) / sec:,.0f};devices={r['devices']}",
+             config={"backend": r["backend"]})
     emit("engine/sharded-table-bytes-per-device",
          r["per_device_table_bytes"],
          f"replicated={r['replicated_table_bytes']}"
          f";district_frac={dfrac:.3f};resident_frac={rfrac:.3f}",
-         unit="bytes")
+         unit="bytes",
+         config={"backend": r["backend"]})
     emit("engine/border-sharded-resident-bytes-per-device",
          r["border_resident_bytes"],
          f"replicated={r['replicated_table_bytes']}"
          f";border_bytes_per_dev={r['border_table_bytes_per_device']}"
          f";border_resident_frac={bfrac:.3f};n={r['n']};q={r['q']}",
-         unit="bytes")
+         unit="bytes",
+         config={"backend": r["backend"]})
 
 
 if __name__ == "__main__":

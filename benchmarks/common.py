@@ -24,17 +24,26 @@ def subprocess_pythonpath(env: dict) -> str:
 
 def run_json_subprocess(code: str, timeout: int = 560) -> dict:
     """Run a Python snippet in a fresh interpreter (PYTHONPATH=src, repo
-    root cwd) and parse the last JSON line it prints."""
+    root cwd) and parse the last JSON line it prints.
+
+    The children measure virtual host-device layouts only, so they are
+    pinned to the CPU platform: on an accelerator host the parent may
+    already hold the chip, and a child reaching for it would fail or
+    hang. The returned dict says so under ``"backend"``; rows built from
+    it carry that in their ``config``."""
     env = dict(os.environ)
     env["PYTHONPATH"] = subprocess_pythonpath(env)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=timeout,
                          cwd=os.path.dirname(os.path.dirname(
                              os.path.abspath(__file__))))
     if out.returncode != 0:
         raise RuntimeError(out.stderr[-1500:])
-    return json.loads([l for l in out.stdout.splitlines()
-                       if l.startswith("{")][-1])
+    result = json.loads([l for l in out.stdout.splitlines()
+                         if l.startswith("{")][-1])
+    result["backend"] = "cpu"
+    return result
 
 
 _ENGINE_SWEEP_TEMPLATE = r"""

@@ -49,11 +49,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                    # jax >= 0.5 exports it at top level
-    _shard_map = jax.shard_map
-except AttributeError:                  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..core.labels import BorderLabels
 from ..core.local_index import LocalIndex
 from ..core.partition import Partition
@@ -312,11 +307,15 @@ def make_sharded_query_fn(mesh: Mesh, axis: str = "edge",
                                             use_pallas=use_pallas,
                                             quant=quant)
 
-    sharded = _shard_map(
+    # check_vma=False: the Pallas join's out_shape carries no varying-
+    # manual-axes annotation, which the checker requires of every value
+    # inside the map; the pmin at the end already makes the output
+    # replicated, as out_specs=P() states
+    sharded = jax.shard_map(
         _device_fn, mesh=mesh,
         in_specs=(P(axis), P(axis) if shard_border else P(),
                   P(), P(), P()),
-        out_specs=P(),
+        out_specs=P(), check_vma=False,
     )
     fn = jax.jit(sharded)
     _FN_CACHE[key] = fn

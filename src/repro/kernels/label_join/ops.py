@@ -26,9 +26,8 @@ Paper map (anchors refer to PAPER.md / the source paper):
   min runs in RAW code units with one final ``· scale`` — so a lossless
   spec serves bit-for-bit the float32 answers at half the bytes. The
   ``quant=`` kwarg threads the same through both sharded entry points;
-  in the B-sharded ragged assembly the cross-device ``pmin`` then runs
-  directly on the 2-byte codes (the sentinel doubles as the min
-  identity), halving the collective traffic too.
+  in the B-sharded ragged assembly the cross-device ``pmin`` runs on the
+  codes widened to int32 (the sentinel doubles as the min identity).
 * ``join_partial_gathered`` — the per-edge-server half of the scatter-
   gather read path (``edge/scatter_gather.py``): one server's min-plus
   partial over pre-assembled label rows (its own district block plus
@@ -279,10 +278,12 @@ def join_sharded_border_gathered(block: jnp.ndarray, bshard: jnp.ndarray,
     exactly like the replicated case.
 
     With ``quant=(sentinel, scale)`` the tables hold ``core.quantize``
-    codes and the ragged assembly ``pmin`` runs directly on the 2-byte
-    codes — the sentinel (the dtype maximum) is the min identity, so
-    non-owners contribute it instead of +inf and the collective moves
-    half the bytes of the float32 layout."""
+    codes and the ragged assembly ``pmin`` runs on the codes widened to
+    int32 — the sentinel (the dtype maximum) is the min identity, so
+    non-owners contribute it instead of +inf. The TPU compiler packs a
+    16-bit min all-reduce two codes to a 32-bit word, and on a v5e 2x2
+    mesh that collective returned wrong B rows; the widened collective
+    moves as many bytes as the float32 layout's."""
     dev = jax.lax.axis_index(axis)
     cross_base = block.shape[0]
     rows_pd = bshard.shape[0]       # = ceil(n/E) ≥ 1 whenever n ≥ 1
@@ -300,7 +301,11 @@ def join_sharded_border_gathered(block: jnp.ndarray, bshard: jnp.ndarray,
     # after the pmin every device holds the true B row for each cross
     # lane (non-owners contributed the min identity); s and t lanes are
     # stacked so both endpoints ride one collective launch
-    both = jax.lax.pmin(jnp.concatenate([ragged(rs), ragged(rt)]), axis)
+    both = jnp.concatenate([ragged(rs), ragged(rt)])
+    if quant is None:
+        both = jax.lax.pmin(both, axis)
+    else:
+        both = jax.lax.pmin(both.astype(jnp.int32), axis).astype(both.dtype)
     if wpad:
         both = jnp.pad(both, ((0, 0), (0, wpad)),
                        constant_values=pad_val)

@@ -21,41 +21,36 @@ from jax.experimental import pallas as pl
 _CHUNK = 8  # contraction chunk = VREG sublane count
 
 
-def _minplus_kernel(a_ref, b_ref, o_ref, *, bk: int):
+def minplus_tile(a_ref, b_ref, acc: jnp.ndarray) -> jnp.ndarray:
+    """min(acc, A ⊗ B) over one (bm,bk)x(bk,bn) VMEM tile pair.
+
+    The contraction is a static, unrolled loop over 8-wide chunks read
+    straight from the refs: static slice bounds are what Mosaic can
+    lower (a ``dynamic_slice`` inside a ``fori_loop`` body is refused)."""
+    for c in range(a_ref.shape[1] // _CHUNK):
+        lo, hi = c * _CHUNK, (c + 1) * _CHUNK
+        ak = a_ref[:, lo:hi]                  # (bm, CHUNK)
+        bk = b_ref[lo:hi, :]                  # (CHUNK, bn)
+        # (bm, CHUNK, bn) broadcast, reduced immediately
+        acc = jnp.minimum(acc, jnp.min(ak[:, :, None] + bk[None, :, :],
+                                       axis=1))
+    return acc
+
+
+def _minplus_kernel(a_ref, b_ref, o_ref):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.full_like(o_ref[...], jnp.inf)
 
-    a = a_ref[...]            # (bm, bk)
-    b = b_ref[...]            # (bk, bn)
-
-    def body(c, acc):
-        ak = jax.lax.dynamic_slice_in_dim(a, c * _CHUNK, _CHUNK, axis=1)
-        bk_ = jax.lax.dynamic_slice_in_dim(b, c * _CHUNK, _CHUNK, axis=0)
-        # (bm, CHUNK, bn) broadcast lives in VREGs, reduced immediately
-        part = jnp.min(ak[:, :, None] + bk_[None, :, :], axis=1)
-        return jnp.minimum(acc, part)
-
-    acc = jax.lax.fori_loop(0, bk // _CHUNK, body, o_ref[...])
-    o_ref[...] = acc
+    o_ref[...] = minplus_tile(a_ref, b_ref, o_ref[...])
 
 
-def _relax_kernel(d_ref, a_ref, carry_ref, o_ref, *, bk: int):
+def _relax_kernel(d_ref, a_ref, carry_ref, o_ref):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = carry_ref[...]       # seed with D tile: fuses min(D, .)
 
-    d = d_ref[...]
-    a = a_ref[...]
-
-    def body(c, acc):
-        dk = jax.lax.dynamic_slice_in_dim(d, c * _CHUNK, _CHUNK, axis=1)
-        ak = jax.lax.dynamic_slice_in_dim(a, c * _CHUNK, _CHUNK, axis=0)
-        part = jnp.min(dk[:, :, None] + ak[None, :, :], axis=1)
-        return jnp.minimum(acc, part)
-
-    acc = jax.lax.fori_loop(0, bk // _CHUNK, body, o_ref[...])
-    o_ref[...] = acc
+    o_ref[...] = minplus_tile(d_ref, a_ref, o_ref[...])
 
 
 def _pad_to(x: jnp.ndarray, m0: int, m1: int) -> jnp.ndarray:
@@ -81,7 +76,7 @@ def minplus_pallas(a: jnp.ndarray, b: jnp.ndarray, *, bm: int = 128,
     _, np_ = b32.shape
     grid = (mp // bm, np_ // bn, kp // bk)
     out = pl.pallas_call(
-        functools.partial(_minplus_kernel, bk=bk),
+        _minplus_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -106,7 +101,7 @@ def relax_pallas(d: jnp.ndarray, a: jnp.ndarray, *, bm: int = 128,
     sp, vp = d32.shape
     grid = (sp // bm, vp // bn, vp // bk)
     out = pl.pallas_call(
-        functools.partial(_relax_kernel, bk=bk),
+        _relax_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),   # D (contract)

@@ -23,12 +23,23 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_CHUNK = 8
+from ..minplus.kernel import minplus_tile
 
 
 def _inblock_fw(d: jnp.ndarray) -> jnp.ndarray:
+    """Floyd–Warshall over one (bk,bk) tile. Pivot column and row k are
+    picked with an iota mask and a min-reduce rather than ``d[:, k]``:
+    Mosaic refuses a dynamic lane index, and the masked min returns the
+    same element bit for bit (every other lane is +inf)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, d.shape, 0)
+
     def body(k, d):
-        return jnp.minimum(d, d[:, k][:, None] + d[k, :][None, :])
+        col = jnp.min(jnp.where(lane == k, d, jnp.inf), axis=1,
+                      keepdims=True)
+        row = jnp.min(jnp.where(sub == k, d, jnp.inf), axis=0,
+                      keepdims=True)
+        return jnp.minimum(d, col + row)
     return jax.lax.fori_loop(0, d.shape[0], body, d)
 
 
@@ -36,29 +47,19 @@ def _phase1_kernel(d_ref, o_ref):
     o_ref[...] = _inblock_fw(d_ref[...])
 
 
-def _minplus_tile(a: jnp.ndarray, b: jnp.ndarray,
-                  acc: jnp.ndarray) -> jnp.ndarray:
-    def body(c, acc):
-        ak = jax.lax.dynamic_slice_in_dim(a, c * _CHUNK, _CHUNK, axis=1)
-        bk = jax.lax.dynamic_slice_in_dim(b, c * _CHUNK, _CHUNK, axis=0)
-        return jnp.minimum(acc, jnp.min(ak[:, :, None] + bk[None, :, :],
-                                        axis=1))
-    return jax.lax.fori_loop(0, a.shape[1] // _CHUNK, body, acc)
-
-
 def _phase2_row_kernel(pivot_ref, row_ref, o_ref):
     # D[kb, j] = min(D[kb, j], pivot ⊗ D[kb, j])
-    o_ref[...] = _minplus_tile(pivot_ref[...], row_ref[...], row_ref[...])
+    o_ref[...] = minplus_tile(pivot_ref, row_ref, row_ref[...])
 
 
 def _phase2_col_kernel(pivot_ref, col_ref, o_ref):
     # D[i, kb] = min(D[i, kb], D[i, kb] ⊗ pivot)
-    o_ref[...] = _minplus_tile(col_ref[...], pivot_ref[...], col_ref[...])
+    o_ref[...] = minplus_tile(col_ref, pivot_ref, col_ref[...])
 
 
 def _phase3_kernel(col_ref, row_ref, d_ref, o_ref):
     # D[i, j] = min(D[i, j], D[i, kb] ⊗ D[kb, j])
-    o_ref[...] = _minplus_tile(col_ref[...], row_ref[...], d_ref[...])
+    o_ref[...] = minplus_tile(col_ref, row_ref, d_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))

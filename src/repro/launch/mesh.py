@@ -12,13 +12,8 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_edge_mesh(num_devices: int | None = None):
-    """1-D mesh for the districts→devices distance-query deployment."""
-    n = num_devices or len(jax.devices())
-    return jax.make_mesh((n,), ("edge",))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # TPU v5e hardware constants used by the roofline analysis

@@ -55,6 +55,18 @@ def test_subprocess_child_has_no_empty_syspath_entry():
     assert any(p.endswith("src") for p in paths)
 
 
+def test_subprocess_child_is_pinned_to_cpu(monkeypatch):
+    """Children measure virtual host-device layouts; on an accelerator
+    host they must never reach for the chip the parent holds, whatever
+    platform the parent asked for."""
+    from benchmarks.common import run_json_subprocess
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    out = run_json_subprocess(
+        "import json, os; "
+        "print(json.dumps({'platforms': os.environ['JAX_PLATFORMS']}))")
+    assert out == {"platforms": "cpu", "backend": "cpu"}
+
+
 # -- telemetry sink ---------------------------------------------------------
 
 def test_telemetry_sink_round_trip(tmp_path):

@@ -10,13 +10,17 @@ import pytest
 
 from repro.configs.base import get_smoke_config
 from repro.distributed.sharding import param_pspecs
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
+
+# jax.make_mesh defaults to Explicit axes; the sharding rules here are
+# written for Auto axes (with_sharding_constraint inside the step)
+AUTO2 = (AxisType.Auto, AxisType.Auto)
 
 
 def test_param_pspecs_shapes_and_rules():
     import jax
     cfg = get_smoke_config("qwen3_4b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=AUTO2)
     from repro.launch.specs import param_specs
     shapes = param_specs(cfg)
     specs = param_pspecs(mesh, cfg, shapes)
@@ -38,7 +42,7 @@ def test_param_pspecs_shapes_and_rules():
 def test_pspec_divisibility_fallback():
     import jax
     cfg = get_smoke_config("starcoder2_7b")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=AUTO2)
     from repro.launch.specs import param_specs
     specs = param_pspecs(mesh, cfg, param_specs(cfg))
     # vocab 512 % 1 == 0 — sharded; the rule itself never errors
@@ -63,7 +67,8 @@ from repro.train.train_step import make_train_step, make_serve_step
 
 cfg = get_smoke_config("qwen3_4b").reduced(num_layers=4, ce_chunk=64,
                                            vocab_size=512)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 shape = ShapeSpec("t", 128, 8, "train")
 specs = {"params": param_specs(cfg)}
 specs["opt"] = opt_specs(specs["params"])
@@ -142,8 +147,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.distributed.checkpoint import save_checkpoint, restore_checkpoint
 
 # save from a (2,4) mesh, restore onto a (4,2) mesh — elastic rescale
-mesh_a = jax.make_mesh((2, 4), ("data", "model"))
-mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+mesh_a = jax.make_mesh((2, 4), ("data", "model"),
+                       axis_types=(jax.sharding.AxisType.Auto,) * 2)
+mesh_b = jax.make_mesh((4, 2), ("data", "model"),
+                       axis_types=(jax.sharding.AxisType.Auto,) * 2)
 x = jnp.arange(64 * 16, dtype=jnp.float32).reshape(64, 16)
 xa = jax.device_put(x, NamedSharding(mesh_a, P("data", "model")))
 with tempfile.TemporaryDirectory() as d:
