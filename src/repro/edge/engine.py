@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import obs
 from ..core.local_index import LocalIndex
 from ..core.quantize import QuantSpec
 from ..kernels.label_join import ops as lj
@@ -77,6 +78,7 @@ def _pad_to_bucket(*cols: np.ndarray) -> list[np.ndarray]:
     itself — on device 0, for the sharded engine — and are sliced off)."""
     qn = len(cols[0])
     qp = lj._ceil_to(qn, lj.PAD_Q)
+    obs.count("serve.pad_pairs", qp - qn)
     out = []
     for c in cols:
         p = np.zeros(qp, dtype=np.int64)
@@ -124,16 +126,19 @@ class BatchedQueryEngine:
         qn = len(ss)
         if qn == 0:
             return np.zeros(0, dtype=np.float32)
-        rs, rt = _pad_to_bucket(*self.row_ids(ss, ts))
-        if self.quant is None:
-            out = _engine_fn(self._table, rs, rt,
-                             use_pallas=self.use_pallas)
-        else:
-            sent, scale = self.quant.key()
-            out = _engine_fn_quantized(self._table, rs, rt,
-                                       use_pallas=self.use_pallas,
-                                       sentinel=sent, scale=scale)
-        return np.asarray(out)[:qn]
+        with obs.span("repro.route"):
+            rs, rt = _pad_to_bucket(*self.row_ids(ss, ts))
+        with obs.span("repro.dispatch"):
+            if self.quant is None:
+                out = _engine_fn(self._table, rs, rt,
+                                 use_pallas=self.use_pallas)
+            else:
+                sent, scale = self.quant.key()
+                out = _engine_fn_quantized(self._table, rs, rt,
+                                           use_pallas=self.use_pallas,
+                                           sentinel=sent, scale=scale)
+        with obs.span("repro.fetch"):
+            return np.asarray(out)[:qn]
 
     __call__ = query
     # QueryPlane conformance: the engine snapshot is the steady-state
@@ -223,9 +228,12 @@ class ShardedBatchedEngine:
         qn = len(ss)
         if qn == 0:
             return np.zeros(0, dtype=np.float32)
-        owner, rs, rt = _pad_to_bucket(*self.row_ids(ss, ts))
-        out = self._fn(self._table, self._btable, owner, rs, rt)
-        return np.asarray(out)[:qn]
+        with obs.span("repro.route"):
+            owner, rs, rt = _pad_to_bucket(*self.row_ids(ss, ts))
+        with obs.span("repro.dispatch"):
+            out = self._fn(self._table, self._btable, owner, rs, rt)
+        with obs.span("repro.fetch"):
+            return np.asarray(out)[:qn]
 
     __call__ = query
     # QueryPlane conformance (see BatchedQueryEngine)

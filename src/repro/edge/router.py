@@ -30,6 +30,7 @@ from typing import TYPE_CHECKING
 
 import numpy as np
 
+from .. import obs
 from ..core.graph import Graph
 from ..core.partition import Partition
 from .center import ComputingCenter
@@ -93,12 +94,15 @@ class EdgeSystem:
     def deploy(cls, g: Graph, part: Partition,
                builder: str = "reference") -> "EdgeSystem":
         center = ComputingCenter(g, part, builder=builder)
-        center.rebuild()
-        servers = [EdgeServer.bootstrap(g, part, i)
-                   for i in range(part.num_districts)]
-        for s in servers:
-            s.install_shortcuts(g, part, center.shortcuts_for(s.district_id),
-                                center.version)
+        with obs.span("repro.deploy.center"):
+            center.rebuild()
+        servers = []
+        for i in range(part.num_districts):
+            with obs.span("repro.deploy.server", district=i):
+                server = EdgeServer.bootstrap(g, part, i)
+                server.install_shortcuts(g, part, center.shortcuts_for(i),
+                                         center.version)
+            servers.append(server)
         return cls(g, part, center, servers)
 
     def apply_traffic_update(self, new_weights: np.ndarray,

@@ -55,6 +55,7 @@ from typing import TYPE_CHECKING, Protocol, Sequence, runtime_checkable
 
 import numpy as np
 
+from .. import obs
 from ..core.query import Rule, bucket_by_rule, route
 
 if TYPE_CHECKING:                                   # pragma: no cover
@@ -340,6 +341,11 @@ class BucketedPlane:
     waited: np.ndarray | None = field(default=None, repr=False)
 
     def execute(self, ss: np.ndarray, ts: np.ndarray) -> np.ndarray:
+        obs.count("serve.window_batches")
+        with obs.span("repro.window"):
+            return self._execute(ss, ts)
+
+    def _execute(self, ss: np.ndarray, ts: np.ndarray) -> np.ndarray:
         sys_ = self.service.system
         ss = np.asarray(ss, dtype=np.int64)
         ts = np.asarray(ts, dtype=np.int64)
@@ -423,14 +429,15 @@ class QueryPlan:
         fallback = getattr(self.plane, "fallback", None)
         waited = getattr(self.plane, "waited", None)
         degraded = getattr(self.plane, "degraded", None)
-        if real is not None:
-            real = np.asarray(real, dtype=bool)
-        batch = ResultBatch(
-            dist, self.service.index_version, latency,
-            (self.service.system.partition.assignment, self.ss, self.ts,
-             self.client_districts),
-            None, codes, fallback, waited, real, degraded)
-        self.service._enqueue(batch)
+        with obs.span("repro.wrap"):
+            if real is not None:
+                real = np.asarray(real, dtype=bool)
+            batch = ResultBatch(
+                dist, self.service.index_version, latency,
+                (self.service.system.partition.assignment, self.ss, self.ts,
+                 self.client_districts),
+                None, codes, fallback, waited, real, degraded)
+            self.service._enqueue(batch)
         return batch
 
 
@@ -479,11 +486,13 @@ class DistanceService:
         batches queue here and are folded in when ``stats`` is read (or
         every ``_MAX_PENDING`` submits)."""
         if self._pending:
-            pending, self._pending = self._pending, []
-            m = len(self._district_load)
-            for batch in pending:
-                self._absorb(batch.counters())
-                self._district_load += batch.district_counts(m)
+            obs.count("serve.folds")
+            with obs.span("repro.fold"):
+                pending, self._pending = self._pending, []
+                m = len(self._district_load)
+                for batch in pending:
+                    self._absorb(batch.counters())
+                    self._district_load += batch.district_counts(m)
         return self._stats
 
     @property
@@ -522,17 +531,19 @@ class DistanceService:
                placement.key() if placement is not None else None)
         if self._plane_cache is not None and self._plane_cache[0] == key:
             return self._plane_cache[1]
-        if p.engine == "scatter_gather":
-            engine = self.system._current_scatter_plane(
-                faults=p.faults, label_dtype=dtype)
-        else:
-            prefer = {"auto": self.system.prefer_sharded,
-                      "replicated": False, "sharded": True}[p.engine]
-            border = (self.system.shard_border if p.shard_border is None
-                      else p.shard_border)
-            engine = self.system._current_engine(prefer_sharded=prefer,
-                                                 shard_border=border,
-                                                 label_dtype=dtype)
+        obs.count("serve.engine_builds")
+        with obs.span("repro.engine.build"):
+            if p.engine == "scatter_gather":
+                engine = self.system._current_scatter_plane(
+                    faults=p.faults, label_dtype=dtype)
+            else:
+                prefer = {"auto": self.system.prefer_sharded,
+                          "replicated": False, "sharded": True}[p.engine]
+                border = (self.system.shard_border if p.shard_border is None
+                          else p.shard_border)
+                engine = self.system._current_engine(prefer_sharded=prefer,
+                                                     shard_border=border,
+                                                     label_dtype=dtype)
         if engine is not None:
             self._plane_cache = (key, engine)
         return engine
@@ -543,16 +554,18 @@ class DistanceService:
         routing itself happens inside the plane — row-id transform for
         the engines, bucket loop for the fallback — so planning costs
         only the freshness check and the cached engine lookup)."""
-        ss = np.asarray(ss, dtype=np.int64)
-        ts = np.asarray(ts, dtype=np.int64)
-        window = any(srv.augmented is None
-                     or srv.augmented_version != self.system.center.version
-                     for srv in self.system.servers)
-        engine = None if window else self._resolve_engine()
-        plane = (engine if engine is not None else
-                 BucketedPlane(self, self.policy.rebuild,
-                               self.policy.use_kernels))
-        return QueryPlan(self, ss, ts, client_districts, plane, window)
+        with obs.span("repro.plan"):
+            ss = np.asarray(ss, dtype=np.int64)
+            ts = np.asarray(ts, dtype=np.int64)
+            obs.count("serve.pairs", len(ss))
+            window = any(srv.augmented is None
+                         or srv.augmented_version != self.system.center.version
+                         for srv in self.system.servers)
+            engine = None if window else self._resolve_engine()
+            plane = (engine if engine is not None else
+                     BucketedPlane(self, self.policy.rebuild,
+                                   self.policy.use_kernels))
+            return QueryPlan(self, ss, ts, client_districts, plane, window)
 
     # -- execution ----------------------------------------------------------
 
@@ -561,7 +574,9 @@ class DistanceService:
                real: np.ndarray | None = None) -> ResultBatch:
         """Answer a batch: ``plan`` + plane dispatch + metadata wrap.
         ``real`` masks padding dummies out of the counters."""
-        return self.plan(ss, ts, client_districts).execute(real=real)
+        obs.count("serve.submits")
+        with obs.span("repro.submit"):
+            return self.plan(ss, ts, client_districts).execute(real=real)
 
     def distances(self, ss: np.ndarray, ts: np.ndarray,
                   client_districts: np.ndarray | None = None) -> np.ndarray:
