@@ -133,6 +133,8 @@ class BatchedQueryEngine:
                 out = _engine_fn(self._table, rs, rt,
                                  use_pallas=self.use_pallas)
             else:
+                if self.use_pallas:     # the kernel widens the codes
+                    obs.count("kernel.join_codes")
                 sent, scale = self.quant.key()
                 out = _engine_fn_quantized(self._table, rs, rt,
                                            use_pallas=self.use_pallas,

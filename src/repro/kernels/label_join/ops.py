@@ -21,8 +21,8 @@ Paper map (anchors refer to PAPER.md / the source paper):
   case. No structure in the serving path is replicated anymore.
 * ``join_quantized`` / ``join_quantized_gathered`` — the same joins over
   uint16/int16 ``core.quantize`` codes: loads stay narrow in HBM, the
-  accumulate widens (int32 on the XLA path, exact float32 into the
-  existing pallas kernel), the sentinel is the absorbing +inf, and the
+  accumulate widens (int32 on the XLA path, exact float32 on the pallas
+  kernel's VMEM tile), the sentinel is the absorbing +inf, and the
   min runs in RAW code units with one final ``· scale`` — so a lossless
   spec serves bit-for-bit the float32 answers at half the bytes. The
   ``quant=`` kwarg threads the same through both sharded entry points;
@@ -81,14 +81,6 @@ def join_with_bound(s_rows: jnp.ndarray, t_rows: jnp.ndarray, *,
     return join_ref(s_rows, t_rows), local_bound_ref(s_rows, t_rows)
 
 
-def _widen_f32(codes: jnp.ndarray, sentinel: int) -> jnp.ndarray:
-    """uint16/int16 codes -> float32 raw values with sentinel -> +inf.
-    Exact: codes < 2^16 ≪ 2^24, so every value (and every pairwise sum)
-    is exactly representable in float32."""
-    return jnp.where(codes == sentinel, jnp.inf,
-                     codes.astype(jnp.float32))
-
-
 def join_quantized(s_codes: jnp.ndarray, t_codes: jnp.ndarray, *,
                    sentinel: int, scale: float,
                    use_pallas: bool = True) -> jnp.ndarray:
@@ -100,15 +92,15 @@ def join_quantized(s_codes: jnp.ndarray, t_codes: jnp.ndarray, *,
     a lossless spec (scale = 1 on integral weights), bitwise identical
     to the float32 ``join`` on the dequantized rows:
 
-    * pallas: widen codes to exact float32 (sentinel → +inf) and reuse
-      the existing f32 kernel — no second kernel to maintain, and
-      +inf · scale = +inf keeps the sentinel an absorbing element;
+    * pallas: the kernel takes the codes as stored and widens each VMEM
+      tile to exact float32 (sentinel → +inf), so no widened copy of the
+      gathered rows reaches HBM; +inf · scale = +inf keeps the sentinel
+      an absorbing element;
     * XLA: widen to an int32 accumulate (sentinel → ``INF_I32``), min
       the integer sums, then map ≥ INF_I32 back to +inf.
     """
     if use_pallas:
-        raw = join_pallas(_widen_f32(s_codes, sentinel),
-                          _widen_f32(t_codes, sentinel),
+        raw = join_pallas(s_codes, t_codes, sentinel=sentinel,
                           interpret=_on_cpu())
         return raw * jnp.float32(scale)
     s = jnp.where(s_codes == sentinel, INF_I32,

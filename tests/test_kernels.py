@@ -4,7 +4,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.label_join.kernel import join_lb_pallas, join_pallas
+from repro.kernels.label_join import ops as lj
+from repro.kernels.label_join.kernel import (WHOLE_WIDTH_VMEM_BYTES,
+                                             join_lb_pallas, join_pallas)
 from repro.kernels.label_join.ref import (join_ref, join_sparse_ref,
                                           local_bound_ref)
 from repro.kernels.minplus.kernel import minplus_pallas, relax_pallas
@@ -71,7 +73,10 @@ def test_closure_matches_numpy_closure():
     np.testing.assert_allclose(got, ref, rtol=1e-5)
 
 
-JOIN_SHAPES = [(1, 1), (5, 7), (64, 128), (100, 257), (512, 512), (3, 1024)]
+# (40, 9000): wider than the whole-width block at bq=32, so the hub axis
+# is tiled by bh=64 and the tail lanes are masked
+JOIN_SHAPES = [(1, 1), (5, 7), (64, 128), (100, 257), (512, 512), (3, 1024),
+               (40, 9000)]
 
 
 @pytest.mark.parametrize("q,h", JOIN_SHAPES)
@@ -82,6 +87,52 @@ def test_join_matches_ref(q, h):
     got = join_pallas(s, t, bq=32, bh=64, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(join_ref(s, t)),
                                rtol=1e-6)
+
+
+def _codes(rng, shape, dtype):
+    """Label codes below the sentinel, sentinel lanes in every row, and
+    a few rows that are all sentinel."""
+    sent = int(np.iinfo(dtype).max)
+    c = rng.integers(0, sent, size=shape).astype(dtype)
+    c[rng.random(shape) < 0.3] = sent
+    c[rng.integers(0, shape[0], size=3)] = sent
+    return c, sent
+
+
+# 2166: the 17x17 deployment's width, past the whole-width block of
+# uint16/int16 rows at bq=256, so the hub axis is tiled and masked
+JOIN_CODE_WIDTHS = [1, 130, 446, 928, 2166]
+
+
+@pytest.mark.parametrize("qn", [256, 300], ids=["rows_whole", "rows_pad"])
+@pytest.mark.parametrize("width", JOIN_CODE_WIDTHS)
+@pytest.mark.parametrize("dtype", [np.uint16, np.int16])
+def test_join_codes_bitwise_match_xla_and_float(dtype, width, qn):
+    """The kernel's in-VMEM widening of stored codes gives, bit for bit,
+    the XLA int32 accumulate and the float32 join of the dequantized
+    rows."""
+    rng = np.random.default_rng(width * 7 + qn)
+    s, sent = _codes(rng, (qn, width), dtype)
+    t, _ = _codes(rng, (qn, width), dtype)
+    if width == 2166:
+        assert 4 * 256 * width * s.itemsize > WHOLE_WIDTH_VMEM_BYTES
+    kernel = np.asarray(join_pallas(jnp.asarray(s), jnp.asarray(t),
+                                    sentinel=sent, interpret=True))
+    assert kernel.dtype == np.float32
+    xla = np.asarray(lj.join_quantized(jnp.asarray(s), jnp.asarray(t),
+                                       sentinel=sent, scale=1.0,
+                                       use_pallas=False))
+    pallas = np.asarray(lj.join_quantized(jnp.asarray(s), jnp.asarray(t),
+                                          sentinel=sent, scale=1.0,
+                                          use_pallas=True))
+
+    def dequant(c):
+        return np.where(c == sent, np.inf, c.astype(np.float32))
+    f32 = np.asarray(join_ref(jnp.asarray(dequant(s)),
+                              jnp.asarray(dequant(t))))
+    assert np.isposinf(f32).any() and np.isfinite(f32).any()
+    for got in (kernel, pallas, f32):
+        np.testing.assert_array_equal(got, xla)
 
 
 @pytest.mark.parametrize("q,h", [(16, 32), (100, 130), (257, 64)])

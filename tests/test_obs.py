@@ -151,6 +151,24 @@ def test_each_engine_submit_yields_the_span_tree(small_system):
     assert "serve.window_batches" not in c
 
 
+def test_kernel_join_codes_counts_each_quantized_kernel_dispatch(
+        small_system):
+    from repro.core.quantize import fit_label_spec
+    from repro.edge import BatchedQueryEngine
+    g, part, system = small_system
+    args = (system.center.border_labels.table,
+            [srv.augmented for srv in system.servers], part.assignment)
+    spec = fit_label_spec(args[0], args[1])
+    ss, ts = _batch(g, 2)
+    BatchedQueryEngine(*args, use_pallas=True).query(ss, ts)
+    BatchedQueryEngine(*args, use_pallas=False, quant=spec).query(ss, ts)
+    assert "kernel.join_codes" not in obs.counters()
+    codes = BatchedQueryEngine(*args, use_pallas=True, quant=spec)
+    for _ in range(3):
+        codes.query(ss, ts)
+    assert obs.counters()["kernel.join_codes"] == 3
+
+
 def test_a_bucketed_submit_is_a_window_batch(small_system):
     g, _, system = small_system
     service = system.service(ServingPolicy(use_kernels=False))

@@ -34,6 +34,8 @@ N, Q_BORDERS, KMAX, BMAX = 73984, 2166, 256, 8
 WIDTH = max(KMAX, Q_BORDERS)
 BATCH = 4096
 SHARDS = 4
+# the dense-matrix benchmark cell: 6x6 districts, q = 928, 256x256 pairs
+DENSE_ROWS, DENSE_WIDTH, DENSE_BATCH = 18432, 928, 65536
 
 
 @pytest.fixture(scope="module")
@@ -100,6 +102,20 @@ def test_single_chip_kernel_compiles(topo, kernels_on, case):
     one = SingleDeviceSharding(topo.devices[0])
     compiled = _single_chip_lowered(case, one).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_quantized_step_joins_codes_inside_the_kernel(topo, kernels_on):
+    """The gathered uint16 rows go straight into the Mosaic kernel: no
+    widened (float32) or padded copy of them is made in HBM."""
+    one = SingleDeviceSharding(topo.devices[0])
+    table = _sds((DENSE_ROWS, DENSE_WIDTH), jnp.uint16, one)
+    ids = _sds((DENSE_BATCH,), jnp.int32, one)
+    text = eng._engine_fn_quantized.lower(
+        table, ids, ids, use_pallas=True, sentinel=65535,
+        scale=1.0).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "pad(" not in text and "convert(" not in text
+    assert f"f32[{DENSE_BATCH}," not in text
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.uint16],
