@@ -14,6 +14,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from .border_labeling import minplus
 from .graph import Graph
 from .labels import BorderLabels, SparseLabels
 from .partition import Partition, borders_of
@@ -39,13 +40,11 @@ class LocalIndex:
 
     def __post_init__(self):
         if self.border_dist is None:
-            k = len(self.vertices)
-            b = len(self.border_locals)
-            bd = np.full((k, b), INF, dtype=np.float32)
-            for j, bloc in enumerate(self.border_locals):
-                bd[:, j] = self.labels.query_many(
-                    np.arange(k), np.full(k, int(bloc)))
-            self.border_dist = bd
+            # λ(v, b) for every (v, border) pair at once: a min-plus
+            # product over the hub-aligned table (hubs are local ids), the
+            # same float32 sums and minima as one query_many per border
+            dense = self.labels.to_dense_hub_table(self.labels.num_vertices)
+            self.border_dist = minplus(dense, dense[self.border_locals].T)
 
     def local_of(self, global_ids: np.ndarray) -> np.ndarray:
         return np.searchsorted(self.vertices, global_ids)

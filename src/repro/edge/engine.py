@@ -222,6 +222,16 @@ class ShardedBatchedEngine:
         q = prepare_queries(self.data, ss, ts)
         return q["owner"], q["rs"], q["rt"]
 
+    def _count(self, owner: np.ndarray, rs: np.ndarray) -> None:
+        """What the mesh adds to a call, over its real lanes: the most
+        lanes one device owns, and with B row-sharded the B rows the
+        ragged assembly brings (two per cross-district lane)."""
+        obs.count("shard.max_owner_lanes",
+                  int(np.bincount(owner, minlength=self.num_devices).max()))
+        if self.shard_border:
+            obs.count("shard.border_rows",
+                      2 * int((rs >= self.data.cross_base).sum()))
+
     def query(self, ss: np.ndarray, ts: np.ndarray) -> np.ndarray:
         """Answer a batch (padded to a PAD_Q bucket exactly like the
         replicated engine, see _pad_to_bucket)."""
@@ -231,8 +241,12 @@ class ShardedBatchedEngine:
         if qn == 0:
             return np.zeros(0, dtype=np.float32)
         with obs.span("repro.route"):
-            owner, rs, rt = _pad_to_bucket(*self.row_ids(ss, ts))
+            owner, rs, rt = self.row_ids(ss, ts)
+            self._count(owner, rs)
+            owner, rs, rt = _pad_to_bucket(owner, rs, rt)
         with obs.span("repro.dispatch"):
+            if self.use_pallas and self.quant is not None:
+                obs.count("kernel.join_codes")
             out = self._fn(self._table, self._btable, owner, rs, rt)
         with obs.span("repro.fetch"):
             return np.asarray(out)[:qn]

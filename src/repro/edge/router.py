@@ -25,6 +25,9 @@ for the coordinator plane (see docs/ARCHITECTURE.md).
 """
 from __future__ import annotations
 
+import multiprocessing
+import os
+from concurrent.futures import ProcessPoolExecutor
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING
 
@@ -34,7 +37,7 @@ from .. import obs
 from ..core.graph import Graph
 from ..core.partition import Partition
 from .center import ComputingCenter
-from .server import EdgeServer
+from .server import EdgeServer, build_server
 
 if TYPE_CHECKING:                                   # pragma: no cover
     from ..serve.service import DistanceService, ServingPolicy
@@ -53,6 +56,34 @@ SHARD_BORDER_AUTO_BYTES = 64 << 20
 # store uint16 codes instead — but ONLY when the fitted spec is lossless
 # (integer-second weights), so auto never changes a single answer
 QUANT_AUTO_BYTES = 32 << 20
+
+# auto-pick for ``EdgeSystem.deploy``: below this many vertices the
+# district builds take less time than starting worker processes does
+DEPLOY_POOL_MIN_VERTICES = 8192
+
+
+def usable_cpus() -> int:
+    """CPUs this process may run on, capped by a cgroup v2 CPU quota
+    (a container's share of a larger host) where one is set."""
+    cpus = (len(os.sched_getaffinity(0)) if hasattr(os, "sched_getaffinity")
+            else os.cpu_count() or 1)
+    try:
+        with open("/sys/fs/cgroup/cpu.max") as f:
+            quota, period = f.read().split()[:2]
+        if quota != "max":
+            cpus = min(cpus, max(1, int(quota) // int(period)))
+    except (OSError, ValueError):
+        pass
+    return cpus
+
+
+def deploy_workers(g: Graph, part: Partition) -> int:
+    """Processes ``EdgeSystem.deploy`` builds the edge servers in: one
+    per usable CPU, at most one per district, and 1 (in this process)
+    for a network under DEPLOY_POOL_MIN_VERTICES."""
+    if g.num_vertices < DEPLOY_POOL_MIN_VERTICES:
+        return 1
+    return max(1, min(usable_cpus(), part.num_districts))
 
 
 @dataclass
@@ -93,16 +124,33 @@ class EdgeSystem:
     @classmethod
     def deploy(cls, g: Graph, part: Partition,
                builder: str = "reference") -> "EdgeSystem":
+        """Center build, then every district's edge server (L_i, then L_i⁺
+        from the center's shortcuts).  The servers are independent, as the
+        paper's edge hosts are, so a large network builds them in
+        ``deploy_workers`` processes.  Either way each server is the
+        same, bit for bit."""
         center = ComputingCenter(g, part, builder=builder)
         with obs.span("repro.deploy.center"):
             center.rebuild()
-        servers = []
-        for i in range(part.num_districts):
-            with obs.span("repro.deploy.server", district=i):
-                server = EdgeServer.bootstrap(g, part, i)
-                server.install_shortcuts(g, part, center.shortcuts_for(i),
-                                         center.version)
-            servers.append(server)
+        m = part.num_districts
+        workers = deploy_workers(g, part)
+        if workers <= 1:
+            servers = []
+            for i in range(m):
+                with obs.span("repro.deploy.server", district=i):
+                    servers.append(build_server(
+                        g, part, i, center.shortcuts_for(i), center.version))
+            return cls(g, part, center, servers)
+        shortcuts = [center.shortcuts_for(i) for i in range(m)]
+        # spawned, not forked: the parent may hold an accelerator runtime.
+        # Each job carries the network (under a MB): a worker's start then
+        # never waits on a large pickle
+        ctx = multiprocessing.get_context("spawn")
+        with obs.span("repro.deploy.server", districts=m, workers=workers), \
+                ProcessPoolExecutor(workers, mp_context=ctx) as pool:
+            servers = list(pool.map(build_server, [g] * m, [part] * m,
+                                    range(m), shortcuts,
+                                    [center.version] * m))
         return cls(g, part, center, servers)
 
     def apply_traffic_update(self, new_weights: np.ndarray,

@@ -207,6 +207,15 @@ class EdgeServer:
         return lam, lam <= lb
 
 
+def build_server(g: Graph, part: Partition, district_id: int,
+                 shortcut_matrix: np.ndarray, version: int) -> EdgeServer:
+    """One district's edge server as deployment leaves it: L_i built,
+    the center's shortcuts for ``version`` folded into L_i⁺."""
+    server = EdgeServer.bootstrap(g, part, district_id)
+    server.install_shortcuts(g, part, shortcut_matrix, version)
+    return server
+
+
 def _build_plain(g: Graph, part: Partition, district_id: int) -> LocalIndex:
     vertices = np.nonzero(part.assignment == np.int32(district_id))[0] \
         .astype(np.int32)

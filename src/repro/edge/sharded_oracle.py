@@ -284,7 +284,8 @@ def make_sharded_query_fn(mesh: Mesh, axis: str = "edge",
                           shard_border: bool = False,
                           quant: tuple[int, float] | None = None):
     """Jitted ``fn(district_block, btable, owner, rs, rt)`` bound to
-    ``mesh``: per-device dense gather-join over [block; B] + one pmin.
+    ``mesh`` (jit name ``_sharded_step``): per-device dense gather-join
+    over [block; B] + one pmin.
     With ``shard_border`` the btable argument is the row-sharded B and
     the touched rows are assembled by ragged gather + pmin first.
     ``quant`` is a ``QuantSpec.key()`` pair when the tables hold
@@ -295,13 +296,15 @@ def make_sharded_query_fn(mesh: Mesh, axis: str = "edge",
     if key in _FN_CACHE:
         return _FN_CACHE[key]
 
+    # the jit takes the mapped function's name: the compiled module is
+    # ``jit__sharded_step``, which a profiler trace lists under that name
     if shard_border:
-        def _device_fn(table, bshard, owner, rs, rt):
+        def _sharded_step(table, bshard, owner, rs, rt):
             return lj.join_sharded_border_gathered(
                 table, bshard, owner, rs, rt,
                 axis=axis, use_pallas=use_pallas, quant=quant)
     else:
-        def _device_fn(table, btable, owner, rs, rt):
+        def _sharded_step(table, btable, owner, rs, rt):
             return lj.join_sharded_gathered(table, btable, owner, rs, rt,
                                             axis=axis,
                                             use_pallas=use_pallas,
@@ -312,7 +315,7 @@ def make_sharded_query_fn(mesh: Mesh, axis: str = "edge",
     # inside the map; the pmin at the end already makes the output
     # replicated, as out_specs=P() states
     sharded = jax.shard_map(
-        _device_fn, mesh=mesh,
+        _sharded_step, mesh=mesh,
         in_specs=(P(axis), P(axis) if shard_border else P(),
                   P(), P(), P()),
         out_specs=P(), check_vma=False,

@@ -244,7 +244,15 @@ def join_sharded_gathered(block: jnp.ndarray, btable: jnp.ndarray,
     else:
         ans = join_quantized(gather(rs), gather(rt), sentinel=quant[0],
                              scale=quant[1], use_pallas=use_pallas)
-    return jax.lax.pmin(jnp.where(owner == dev, ans, jnp.inf), axis)
+    return _answer_pmin(owner == dev, ans, axis)
+
+
+def _answer_pmin(owned: jnp.ndarray, ans: jnp.ndarray,
+                 axis: str) -> jnp.ndarray:
+    """The answer vector over ``axis``: each device keeps the lanes it
+    owns and contributes +inf for the rest."""
+    with jax.named_scope("answer_pmin"):
+        return jax.lax.pmin(jnp.where(owned, ans, jnp.inf), axis)
 
 
 def join_sharded_border_gathered(block: jnp.ndarray, bshard: jnp.ndarray,
@@ -293,11 +301,13 @@ def join_sharded_border_gathered(block: jnp.ndarray, bshard: jnp.ndarray,
     # after the pmin every device holds the true B row for each cross
     # lane (non-owners contributed the min identity); s and t lanes are
     # stacked so both endpoints ride one collective launch
-    both = jnp.concatenate([ragged(rs), ragged(rt)])
-    if quant is None:
-        both = jax.lax.pmin(both, axis)
-    else:
-        both = jax.lax.pmin(both.astype(jnp.int32), axis).astype(both.dtype)
+    with jax.named_scope("border_rows"):
+        both = jnp.concatenate([ragged(rs), ragged(rt)])
+        if quant is None:
+            both = jax.lax.pmin(both, axis)
+        else:
+            both = jax.lax.pmin(both.astype(jnp.int32),
+                                axis).astype(both.dtype)
     if wpad:
         both = jnp.pad(both, ((0, 0), (0, wpad)),
                        constant_values=pad_val)
@@ -315,7 +325,7 @@ def join_sharded_border_gathered(block: jnp.ndarray, bshard: jnp.ndarray,
         ans = join_quantized(gather(rs, bs_rows), gather(rt, bt_rows),
                              sentinel=quant[0], scale=quant[1],
                              use_pallas=use_pallas)
-    return jax.lax.pmin(jnp.where(owner == dev, ans, jnp.inf), axis)
+    return _answer_pmin(owner == dev, ans, axis)
 
 
 def bound_gathered(border_dist: np.ndarray, ss: np.ndarray,

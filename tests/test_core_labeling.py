@@ -163,6 +163,26 @@ def test_plain_local_index_is_district_exact_but_global_upper_bound():
 # Local bound (Definition 5, Theorem 3)
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("gi", range(3))
+def test_border_dist_matches_one_query_many_per_border(gi):
+    """The min-plus ``border_dist`` against the loop it replaced, bit for
+    bit, on plain and shortcut-augmented indexes (non-integral weights
+    included)."""
+    g = small_graphs()[gi]
+    part = bfs_grow_partition(g, 4, seed=1)
+    bl = build_border_labels_reference(g, part)
+    for idx in (build_all_local_indexes(g, part, bl=None)
+                + build_all_local_indexes(g, part, bl=bl)):
+        k = len(idx.vertices)
+        ref = np.full((k, len(idx.border_locals)), np.inf, np.float32)
+        for j, b in enumerate(idx.border_locals):
+            ref[:, j] = idx.labels.query_many(np.arange(k),
+                                              np.full(k, int(b)))
+        assert idx.border_dist.dtype == np.float32
+        assert np.array_equal(idx.border_dist.view(np.uint32),
+                              ref.view(np.uint32))
+
+
 def test_theorem3_certified_answers_are_exact():
     for g in small_graphs():
         part = bfs_grow_partition(g, 4, seed=0)

@@ -169,6 +169,35 @@ def test_kernel_join_codes_counts_each_quantized_kernel_dispatch(
     assert obs.counters()["kernel.join_codes"] == 3
 
 
+@pytest.mark.parametrize("shard_border", [False, True])
+def test_sharded_engine_counts_what_the_mesh_adds(small_system,
+                                                  shard_border):
+    """On one device every real lane is owned by device 0; the B rows
+    are counted only where B is row-sharded, two per cross-district
+    lane, and padding lanes count in neither."""
+    from repro.core.quantize import fit_label_spec
+    from repro.edge import ShardedBatchedEngine
+    g, part, system = small_system
+    args = (system.center.border_labels.table,
+            [srv.augmented for srv in system.servers], part.assignment)
+    spec = fit_label_spec(args[0], args[1])
+    engine = ShardedBatchedEngine(*args, use_pallas=True,
+                                  shard_border=shard_border, quant=spec)
+    batches = [_batch(g, k) for k in range(2)]
+    for ss, ts in batches:
+        engine.query(ss, ts)
+    c = obs.counters()
+    assert c["shard.max_owner_lanes"] == 2 * 200
+    assert c["kernel.join_codes"] == 2
+    cross = sum(int((part.assignment[ss] != part.assignment[ts]).sum())
+                for ss, ts in batches)
+    assert 0 < cross < 400
+    if shard_border:
+        assert c["shard.border_rows"] == 2 * cross
+    else:
+        assert "shard.border_rows" not in c
+
+
 def test_a_bucketed_submit_is_a_window_batch(small_system):
     g, _, system = small_system
     service = system.service(ServingPolicy(use_kernels=False))

@@ -36,6 +36,9 @@ BATCH = 4096
 SHARDS = 4
 # the dense-matrix benchmark cell: 6x6 districts, q = 928, 256x256 pairs
 DENSE_ROWS, DENSE_WIDTH, DENSE_BATCH = 18432, 928, 65536
+# the mesh4-trips benchmark cell: 8x8 districts of 256 vertices, 16 a
+# chip, q about 1,101 (= W), 1024-trip requests
+MESH_N, MESH_Q, MESH_DPD, MESH_BATCH = 16384, 1101, 16, 1024
 
 
 @pytest.fixture(scope="module")
@@ -139,3 +142,20 @@ def test_sharded_step_compiles_on_4_chips(topo, kernels_on, shard_border,
     text = fn.lower(block, btable, ids, ids, ids).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" in text     # the pmin that assembles the answers
+
+
+def test_mesh4_cell_step_compiles_on_4_chips(topo, kernels_on):
+    """The sharded step at the mesh4-trips cell's shapes and layout:
+    uint16 codes, B row-sharded."""
+    mesh = Mesh(np.array(topo.devices[:SHARDS]), ("edge",))
+    fn = make_sharded_query_fn(mesh, "edge", use_pallas=True,
+                               shard_border=True, quant=(65535, 1.0))
+    edge, rep = NamedSharding(mesh, P("edge")), NamedSharding(mesh, P())
+    block = _sds((SHARDS * MESH_DPD * KMAX, MESH_Q), jnp.uint16, edge)
+    btable = _sds((MESH_N, MESH_Q), jnp.uint16, edge)
+    ids = _sds((MESH_BATCH,), jnp.int32, rep)
+    text = fn.lower(block, btable, ids, ids, ids).compile().as_text()
+    assert text.startswith("HloModule jit__sharded_step")
+    assert "tpu_custom_call" in text
+    # the border-row assembly's pmin and the answers' pmin
+    assert text.count(" all-reduce(") == 2
