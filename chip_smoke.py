@@ -44,8 +44,7 @@ import numpy as np  # noqa: E402
 from repro.core import bidirectional_dijkstra  # noqa: E402
 from repro.edge import (BatchedQueryEngine, EdgeSystem,  # noqa: E402
                         ShardedBatchedEngine)
-from repro.edge.engine import (_engine_fn, _engine_fn_quantized,  # noqa: E402
-                               _pad_to_bucket)
+from repro.edge.engine import _engine_fn, _engine_fn_quantized  # noqa: E402
 from repro.ingest import synthetic_continent  # noqa: E402
 from repro.serve import ServingPolicy  # noqa: E402
 from repro.update import scenario_weights  # noqa: E402
@@ -135,11 +134,11 @@ def check_kernel_step(engine, ss: np.ndarray, ts: np.ndarray) -> None:
     Pallas join (a Mosaic ``tpu_custom_call``)."""
     assert engine.use_pallas, "engine chose the XLA reference join"
     if isinstance(engine, ShardedBatchedEngine):
-        owner, rs, rt = _pad_to_bucket(*engine.row_ids(ss, ts))
+        owner, rs, rt = engine.row_ids(ss, ts)
         lowered = engine._fn.lower(engine._table, engine._btable,
                                    owner, rs, rt)
     else:
-        rs, rt = _pad_to_bucket(*engine.row_ids(ss, ts))
+        rs, rt = engine.row_ids(ss, ts)
         if engine.quant is None:
             lowered = _engine_fn.lower(engine._table, rs, rt,
                                        use_pallas=True)

@@ -72,19 +72,14 @@ def _engine_fn_quantized(table, rs, rt, use_pallas: bool,
                              scale=scale, use_pallas=use_pallas)
 
 
-def _pad_to_bucket(*cols: np.ndarray) -> list[np.ndarray]:
-    """Zero-pad row-id columns up to a multiple of PAD_Q so the jit only
-    ever sees a bounded set of shapes (padding lanes join row 0 against
-    itself — on device 0, for the sharded engine — and are sliced off)."""
-    qn = len(cols[0])
+def _bucket(qn: int) -> int:
+    """The length a batch of ``qn`` pairs is padded to: the next multiple
+    of PAD_Q, so the jit only ever sees a bounded set of shapes (routing
+    writes zeros into the padding lanes, which join row 0 against itself
+    — on device 0, for the sharded engine — and are sliced off)."""
     qp = lj._ceil_to(qn, lj.PAD_Q)
     obs.count("serve.pad_pairs", qp - qn)
-    out = []
-    for c in cols:
-        p = np.zeros(qp, dtype=np.int64)
-        p[:qn] = c
-        out.append(p)
-    return out
+    return qp
 
 
 class BatchedQueryEngine:
@@ -114,20 +109,21 @@ class BatchedQueryEngine:
     def row_ids(self, ss: np.ndarray, ts: np.ndarray
                 ) -> tuple[np.ndarray, np.ndarray]:
         """Host-side batch transform: §4.2 routing collapsed into combined-
-        table row ids, one vectorized NumPy pass (the one-shard case of
-        the mesh routing pass — every query is 'owned' by device 0)."""
-        q = prepare_queries(self.data, ss, ts)
+        table row ids (the one-shard case of the mesh routing pass —
+        every query is 'owned' by device 0), int32 and padded to the
+        PAD_Q bucket the jitted step takes (see _bucket)."""
+        q = prepare_queries(self.data, ss, ts, _bucket(len(ss)))
         return q["rs"], q["rt"]
 
     def query(self, ss: np.ndarray, ts: np.ndarray) -> np.ndarray:
-        """Answer a batch (padded to a PAD_Q bucket, see _pad_to_bucket)."""
+        """Answer a batch (padded to a PAD_Q bucket, see _bucket)."""
         ss = np.asarray(ss, dtype=np.int64)
         ts = np.asarray(ts, dtype=np.int64)
         qn = len(ss)
         if qn == 0:
             return np.zeros(0, dtype=np.float32)
         with obs.span("repro.route"):
-            rs, rt = _pad_to_bucket(*self.row_ids(ss, ts))
+            rs, rt = self.row_ids(ss, ts)
         with obs.span("repro.dispatch"):
             if self.quant is None:
                 out = _engine_fn(self._table, rs, rt,
@@ -218,8 +214,9 @@ class ShardedBatchedEngine:
 
     def row_ids(self, ss: np.ndarray, ts: np.ndarray
                 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Host routing pass → (owner device, per-device s row, t row)."""
-        q = prepare_queries(self.data, ss, ts)
+        """Host routing pass → (owner device, per-device s row, t row),
+        int32 and padded to the PAD_Q bucket (see _bucket)."""
+        q = prepare_queries(self.data, ss, ts, _bucket(len(ss)))
         return q["owner"], q["rs"], q["rt"]
 
     def _count(self, owner: np.ndarray, rs: np.ndarray) -> None:
@@ -234,7 +231,7 @@ class ShardedBatchedEngine:
 
     def query(self, ss: np.ndarray, ts: np.ndarray) -> np.ndarray:
         """Answer a batch (padded to a PAD_Q bucket exactly like the
-        replicated engine, see _pad_to_bucket)."""
+        replicated engine, see _bucket)."""
         ss = np.asarray(ss, dtype=np.int64)
         ts = np.asarray(ts, dtype=np.int64)
         qn = len(ss)
@@ -242,8 +239,7 @@ class ShardedBatchedEngine:
             return np.zeros(0, dtype=np.float32)
         with obs.span("repro.route"):
             owner, rs, rt = self.row_ids(ss, ts)
-            self._count(owner, rs)
-            owner, rs, rt = _pad_to_bucket(owner, rs, rt)
+            self._count(owner[:qn], rs[:qn])
         with obs.span("repro.dispatch"):
             if self.use_pallas and self.quant is not None:
                 obs.count("kernel.join_codes")

@@ -263,7 +263,7 @@ class ScatterGatherPlane:
                 # a cross lane reads the server's OWN B row on the
                 # s-side and the peer district's on the t-side
                 self._ensure_rows(d, np.append(
-                    self.data.assignment[rt_d[cross_t] - kmax], d))
+                    self.data.district[rt_d[cross_t] - kmax], d))
             vals = lj.join_partial_gathered(
                 self._gather(d, rs_d), self._gather(d, rt_d),
                 use_pallas=self.use_pallas)
@@ -347,7 +347,7 @@ class ScatterGatherPlane:
         inj.tick()
         qn = len(ss)
         kmax = self.data.kmax
-        assignment = self.data.assignment
+        assignment = self.data.district
         out = np.full(qn, INF, dtype=np.float32)
         codes = np.zeros(qn, dtype=np.uint8)
         reasons = np.full(qn, None, dtype=object)

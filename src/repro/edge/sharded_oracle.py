@@ -22,7 +22,8 @@ This is how a label store scales past a single device's memory: every
 structure is partitioned, so the per-device footprint is ~1/E of the
 full index.
 
-A query batch is preprocessed on the host into (owner, row) coordinates:
+A query batch is preprocessed on the host into (owner, row) coordinates,
+read from per-vertex int32 columns that packing builds once:
 
   rule 1/2 — owner = the device holding district d (blocked assignment
              ``d // dpd``), row = the query endpoint's slot in that
@@ -69,8 +70,13 @@ class ShardedOracleData:
     leading axis shards evenly over the mesh too."""
     district_table: np.ndarray | None  # (m_pad·kmax, W) — shardable
     btable: np.ndarray | None   # (n_pad, q) — center table B
-    local_pos: np.ndarray       # (n,) int64: global id → local slot
-    assignment: np.ndarray      # (n,) int64: global id → district
+    # per-vertex routing columns, int32 (4 B a vertex each), built once
+    # at pack time from the district → (device, slot) placement; routing
+    # state, so they survive release_host_tables
+    district: np.ndarray        # (n,) vertex → its district
+    home: np.ndarray            # (n,) vertex → its rule-1/2 row, in its
+    #                             owner's block: slot·kmax + local slot
+    owner: np.ndarray           # (n,) vertex → device holding its district
     kmax: int
     num_devices: int
     num_districts: int
@@ -93,13 +99,6 @@ class ShardedOracleData:
     # set ⇒ tables hold quantized integer codes (core.quantize); the
     # device joins are handed quant.key() and answers stay float32
     quant: QuantSpec | None = None
-    # district → (device, in-device slot) routing table.  None = the
-    # blocked default (district i on device i // dpd at slot i % dpd);
-    # a migration-produced placement packs each device's resident
-    # districts into slots 0..count-1 instead.  Routing-only state: it
-    # survives release_host_tables.
-    device_of: np.ndarray | None = None    # (m,) int64
-    slot_of: np.ndarray | None = None      # (m,) int64
 
     def __post_init__(self):
         self.districts_per_device = (self.district_table.shape[0]
@@ -109,12 +108,8 @@ class ShardedOracleData:
         self.border_rows_per_device = (
             self.btable.shape[0] // self.num_devices
             if self.border_sharded else self.btable.shape[0])
-        self.num_vertices = len(self.local_pos)
+        self.num_vertices = len(self.district)
         self.itemsize = int(self.district_table.dtype.itemsize)
-        if self.device_of is None:
-            ids = np.arange(self.num_districts, dtype=np.int64)
-            self.device_of = ids // self.districts_per_device
-            self.slot_of = ids % self.districts_per_device
 
     @property
     def cross_base(self) -> int:
@@ -180,17 +175,21 @@ def pack_tables(btable: np.ndarray, locals_: list[LocalIndex],
     repartitioner's ``EdgePlacement.host_of`` with one host per device);
     each device's resident districts are packed into its slots
     ``0..count-1`` and the block height becomes the *maximum* per-device
-    district count.  ``None`` keeps the blocked default — bitwise
-    identical to the same call before placements existed."""
+    district count.  ``None`` keeps the blocked default (district i on
+    device ``i // dpd`` at slot ``i % dpd``).
+
+    The placement is folded into the per-vertex int32 routing columns
+    (``district``, ``home``, ``owner``) that ``prepare_queries`` reads;
+    every row id must fit int32, so packing raises when
+    ``dpd·kmax + n`` (one past the last row of B) reaches 2**31."""
     assert not (combined and shard_border), \
         "combined packing keeps B inside the single replicated buffer"
     n = len(assignment)
     m = len(locals_)
     if placement is None:
         dpd = -(-m // num_devices)
-        device_of = slot_of = None          # blocked default, derived
         ids = np.arange(m, dtype=np.int64)
-        base_dev, base_slot = ids // dpd, ids % dpd
+        device_of, slot_of = ids // dpd, ids % dpd
     else:
         device_of = np.asarray(placement, dtype=np.int64)
         if device_of.shape != (m,):
@@ -205,9 +204,10 @@ def pack_tables(btable: np.ndarray, locals_: list[LocalIndex],
         for dev in range(num_devices):
             resident = np.nonzero(device_of == dev)[0]
             slot_of[resident] = np.arange(len(resident))
-        base_dev, base_slot = device_of, slot_of
     m_pad = dpd * num_devices
     kmax = max(len(li.vertices) for li in locals_)
+    if dpd * kmax + n >= 2**31:
+        raise ValueError(f"row ids up to {dpd * kmax + n} do not fit int32")
     q = btable.shape[1]
     width = max(kmax, q, 1)
     rows = m_pad * kmax
@@ -238,14 +238,15 @@ def pack_tables(btable: np.ndarray, locals_: list[LocalIndex],
     local_pos = np.zeros(n, dtype=np.int64)
     for i, li in enumerate(locals_):
         k = len(li.vertices)
-        base = (base_dev[i] * dpd + base_slot[i]) * kmax
+        base = (device_of[i] * dpd + slot_of[i]) * kmax
         table[base:base + k, :k] = enc(li.dense_table())
         local_pos[li.vertices] = np.arange(k, dtype=np.int64)
-    return ShardedOracleData(table, bt, local_pos,
-                             assignment.astype(np.int64), kmax,
+    district = np.asarray(assignment).astype(np.int32)
+    home = (slot_of[district] * kmax + local_pos).astype(np.int32)
+    owner = device_of[district].astype(np.int32)
+    return ShardedOracleData(table, bt, district, home, owner, kmax,
                              num_devices, m, combined_table=buf,
-                             border_sharded=shard_border, quant=quant,
-                             device_of=device_of, slot_of=slot_of)
+                             border_sharded=shard_border, quant=quant)
 
 
 def pack_for_mesh(part: Partition, bl: BorderLabels,
@@ -259,21 +260,32 @@ def pack_for_mesh(part: Partition, bl: BorderLabels,
 
 
 def prepare_queries(data: ShardedOracleData, ss: np.ndarray,
-                    ts: np.ndarray) -> dict[str, np.ndarray]:
-    """Host-side client/edge-server routing pass: one vectorized NumPy
-    sweep emits each query's owning device and the two per-device row ids
-    its gather-join reads (§4.2 rules collapsed into coordinates)."""
-    ss = np.asarray(ss, dtype=np.int64)
-    ts = np.asarray(ts, dtype=np.int64)
-    ds = data.assignment[ss]
-    cross = ds != data.assignment[ts]
-    # routing reads the packed placement table (blocked default:
-    # device i // dpd, slot i % dpd — identical coordinates to the
-    # historical arithmetic)
-    slot_base = data.slot_of[ds] * data.kmax
-    rs = np.where(cross, data.cross_base + ss, slot_base + data.local_pos[ss])
-    rt = np.where(cross, data.cross_base + ts, slot_base + data.local_pos[ts])
-    return {"owner": data.device_of[ds], "rs": rs, "rt": rt}
+                    ts: np.ndarray, size: int | None = None
+                    ) -> dict[str, np.ndarray]:
+    """Host-side client/edge-server routing pass: each query's owning
+    device and the two per-device row ids its gather-join reads (§4.2
+    rules collapsed into coordinates), int32, from the per-vertex
+    columns. A lane whose endpoints share a district (rule 1/2) reads
+    both ``home`` rows on the device of that district; any other lane
+    (rule 3) reads ``cross_base + v`` for both endpoints, their rows of
+    B, on the device of the source's district. ``size`` (default: the
+    batch length) is the length of the returned arrays: lanes past the
+    batch are zero, the padding a jitted step's bucket takes."""
+    ss = np.asarray(ss)
+    ts = np.asarray(ts)
+    qn = len(ss)
+    size = qn if size is None else size
+    local = np.flatnonzero(data.district.take(ss) == data.district.take(ts))
+    out = {}
+    for key, v in (("rs", ss), ("rt", ts)):
+        rows = np.zeros(size, dtype=np.int32)
+        np.add(v, data.cross_base, out=rows[:qn], casting="unsafe")
+        rows[local] = data.home.take(v.take(local))
+        out[key] = rows
+    out["owner"] = np.zeros(size, dtype=np.int32)
+    if data.num_devices > 1:        # on one device every lane is its own
+        out["owner"][:qn] = data.owner.take(ss)
+    return out
 
 
 _FN_CACHE: dict = {}
