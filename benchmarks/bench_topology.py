@@ -131,7 +131,7 @@ def _migration_section(quick: bool) -> None:
     # pack memcpys cached dense tables — only coordinates move)
     t0 = time.perf_counter()
     system.migrate(plan)
-    engine = system._current_engine(prefer_sharded=True)
+    engine = system._current_engine(ServingPolicy(engine="sharded"))
     _ = engine.query(np.zeros(8, np.int64), np.zeros(8, np.int64))
     swap_s = time.perf_counter() - t0
     emit("topology/migrate-swap", swap_s * 1e3,

@@ -43,8 +43,8 @@ def main() -> None:
 
     from . import (bench_edge, bench_indexing, bench_ingest,
                    bench_kernels, bench_lm, bench_load,
-                   bench_oracle_sharding, bench_query, bench_scatter,
-                   bench_topology, bench_update)
+                   bench_oracle_sharding, bench_query, bench_topology,
+                   bench_update)
     suites = {
         "indexing": bench_indexing.run,   # Table 2
         "query": bench_query.run,         # Fig. 5
@@ -55,7 +55,6 @@ def main() -> None:
         "update": bench_update.run,       # incremental repair sweep
         "topology": bench_topology.run,   # closures + migration (repro.topo)
         "load": bench_load.run,           # open-loop million-user harness
-        "scatter": bench_scatter.run,     # cross-edge scatter-gather plane
         "ingest": bench_ingest.run,       # continent-scale ingest + quantize
     }
     sink = None

@@ -4,11 +4,8 @@ shard_map deployment."""
 from .topology import LatencyModel, Topology
 from .center import ComputingCenter
 from .server import EdgeServer
-from .router import EdgeSystem
+from .router import EdgeSystem, EngineLayout, choose_layout
 from .engine import BatchedQueryEngine, ShardedBatchedEngine
-from .scatter_gather import ScatterGatherPlane
-from .faults import (NO_FAULTS, FaultInjector, FaultPlan,
-                     district_outage_storm, link_loss_sweep)
 from .simulator import (BatchPolicy, MigrationEvent, QueryEvent, SimResult,
                         UpdateSchedule, VariableUpdateSchedule, make_trace,
                         migrations_from_plan, run_update_epochs,

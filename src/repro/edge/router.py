@@ -11,17 +11,16 @@ for two PRs and are now removed.)
 
 Paper map: the service planes implement the §4.2 query rules (rule 1
 same-district local, rule 2 same-district via another client's server,
-rule 3 cross-district through the border table B — answered at the
-computing center by the engine planes, or entirely edge-side by the
-scatter-gather plane's peer border-row exchange); during a rebuild
+rule 3 cross-district through the border table B); during a rebuild
 window (center pushed a new index version, shortcuts not yet installed)
 answers are served from the stale L_i under the Theorem-3
 rebuild-window certificate (λ ≤ Local Bound ⇒ still exact), and the
 uncertified residue is resolved per the policy's rebuild mode.
-``_current_engine`` snapshots one index version into a batched serving
-engine and swaps it — including the device-resident B shards — whenever
-the center's version moves; ``_current_scatter_plane`` does the same
-for the coordinator plane (see docs/ARCHITECTURE.md).
+``choose_layout`` is the one place the steady-state engine's layout is
+decided; ``_current_engine`` snapshots one index version into an engine
+of that layout and swaps it — including the device-resident B shards —
+whenever the center's version, the placement or the layout moves (see
+docs/ARCHITECTURE.md).
 """
 from __future__ import annotations
 
@@ -42,13 +41,9 @@ from .server import EdgeServer, build_server
 if TYPE_CHECKING:                                   # pragma: no cover
     from ..serve.service import DistanceService, ServingPolicy
 
-# sentinel: "use the EdgeSystem attribute" (None already means auto-pick)
-_SELF = object()
-
 # auto-pick threshold for row-sharding the border table B: replicating B
 # costs n·q·4 bytes per device and zero collectives, so it stays
-# replicated until it is big enough to matter (override per-system with
-# ``EdgeSystem.shard_border``)
+# replicated until it is big enough to matter
 SHARD_BORDER_AUTO_BYTES = 64 << 20
 
 # auto-pick threshold for quantized label storage: once the float32
@@ -60,6 +55,43 @@ QUANT_AUTO_BYTES = 32 << 20
 # auto-pick for ``EdgeSystem.deploy``: below this many vertices the
 # district builds take less time than starting worker processes does
 DEPLOY_POOL_MIN_VERTICES = 8192
+
+
+@dataclass(frozen=True)
+class EngineLayout:
+    """How the steady-state engine holds one index version."""
+    sharded: bool           # district tables sharded over the devices
+    shard_border: bool      # B row-sharded (only inside the sharded engine)
+    label_dtype: str        # storage: "float32", "uint16" or "int16"
+
+
+def choose_layout(engine: str, shard_border: bool | None, label_dtype: str,
+                  num_devices: int, border_bytes: int, index_bytes: int,
+                  lossless: bool) -> EngineLayout:
+    """The steady-state engine layout, decided in one place.
+
+    ``engine`` (``auto``/``replicated``/``sharded``), ``shard_border``
+    and ``label_dtype`` are the ``ServingPolicy`` knobs; the rest is
+    what the system observes: its device count, B's float32 bytes
+    (n·q·4), the float32 bytes of the whole index (B plus the dense
+    district tables), and whether the uint16 spec fitted to the tables
+    is lossless.
+
+    * sharded: under ``auto`` iff there is more than one device;
+    * B row-sharded: only inside the sharded engine, and under ``None``
+      once B is over SHARD_BORDER_AUTO_BYTES;
+    * storage: under ``auto`` uint16 once the index is over
+      QUANT_AUTO_BYTES and the fit is lossless (so auto never changes
+      an answer), else float32; an explicit dtype is honored even when
+      the fit is lossy.
+    """
+    sharded = num_devices > 1 if engine == "auto" else engine == "sharded"
+    border = sharded and (border_bytes > SHARD_BORDER_AUTO_BYTES
+                          if shard_border is None else shard_border)
+    if label_dtype == "auto":
+        label_dtype = ("uint16" if index_bytes > QUANT_AUTO_BYTES and lossless
+                       else "float32")
+    return EngineLayout(sharded, border, label_dtype)
 
 
 def usable_cpus() -> int:
@@ -95,31 +127,19 @@ class EdgeSystem:
     stats: dict = field(default_factory=lambda: {
         "rule1": 0, "rule2": 0, "rule3": 0, "lb_certified": 0,
         "lb_fallback_attempts": 0})
-    # engine selection: None = auto (sharded iff the backend exposes more
-    # than one device), True/False = force sharded/replicated
-    prefer_sharded: bool | None = None
-    # border-table placement within the sharded engine: None = auto (row-
-    # shard B once its replicated footprint n·q·4 exceeds
-    # SHARD_BORDER_AUTO_BYTES), True/False = force sharded/replicated B.
-    # Only consulted when the sharded engine is selected.
-    shard_border: bool | None = None
-    # label storage dtype: None/"auto" = float32 until the index crosses
-    # QUANT_AUTO_BYTES and the fitted uint16 spec is lossless;
-    # "float32" / "uint16" / "int16" force the storage (an explicit
-    # integer dtype is honored even when the fit is lossy)
-    label_dtype: str | None = None
     # district → edge-host routing table (repro.topo.rebalance); None =
     # the blocked default layout.  ``migrate`` swaps it atomically — its
-    # version joins every engine/plane cache key, so the next batch
-    # routes on the new table while in-flight batches keep the snapshot
-    # (= the old owner) they started with
+    # version joins the engine cache key, so the next batch routes on
+    # the new table while in-flight batches keep the snapshot (= the old
+    # owner) they started with
     placement: object | None = None
-    # steady-state serving engine, snapshot of one index version
+    # steady-state serving engine: one index version in one layout,
+    # keyed on (snapshot_key(), layout); setting _engine to None forces
+    # the next call to rebuild
     _engine: object | None = field(default=None, repr=False)
     _engine_key: tuple | None = field(default=None, repr=False)
-    # scatter-gather coordinator plane, same snapshot discipline
-    _scatter: object | None = field(default=None, repr=False)
-    _scatter_key: tuple | None = field(default=None, repr=False)
+    # (index version, uint16 spec fitted to that version's tables)
+    _fit: tuple | None = field(default=None, repr=False)
 
     @classmethod
     def deploy(cls, g: Graph, part: Partition,
@@ -286,7 +306,7 @@ class EdgeSystem:
         """Install a new district → host placement atomically (the
         ``RebalancePlanner`` execute step).
 
-        The placement version joins every engine/plane cache key, so
+        The placement version joins the engine cache key, so
         the swap is a pointer write: batches planned after this call
         route on the new table (the next ``_current_engine`` call
         re-packs the moved districts' blocks — unmoved districts'
@@ -323,133 +343,106 @@ class EdgeSystem:
         for k, v in counters.items():
             self.stats[k] += v
 
-    def _resolve_quant(self, label_dtype):
-        """Map a ``label_dtype`` knob value to the QuantSpec the planes
-        pack with (None ⇒ float32 storage).  Auto quantizes only when
-        the float32 index footprint crosses QUANT_AUTO_BYTES AND the
-        fitted uint16 spec round-trips losslessly — so turning auto on
-        can never change an answer.  An explicit integer dtype is
-        honored even when lossy (the caller asked for the bytes)."""
-        from ..core.quantize import LABEL_DTYPES, fit_label_spec
+    def snapshot_key(self) -> tuple:
+        """What an engine snapshot is valid for besides its layout: the
+        center's index version and the placement's key.  Outside a
+        rebuild window every server is at the center's version."""
+        placement = self.placement
+        return (self.center.version,
+                None if placement is None else placement.key())
+
+    def _fitted_spec(self):
+        """The uint16 ``QuantSpec`` fitted to the current index's
+        tables, memoized per index version (the fit reads every
+        table)."""
+        from ..core.quantize import fit_label_spec
+        version = self.center.version
+        if self._fit is None or self._fit[0] != version:
+            self._fit = (version, fit_label_spec(
+                self.center.border_labels.table,
+                [srv.augmented for srv in self.servers]))
+        return self._fit[1]
+
+    def layout(self, policy: "ServingPolicy | None" = None) -> EngineLayout:
+        """``choose_layout`` for ``policy`` (None: the default policy) on
+        this backend's devices and the current index's tables.  Call it
+        only outside a rebuild window."""
+        import jax
+        engine, shard_border, label_dtype = (
+            ("auto", None, "auto") if policy is None
+            else (policy.engine, policy.shard_border, policy.label_dtype))
+        btable = self.center.border_labels.table
+        index_bytes = 4 * (btable.size + sum(len(srv.augmented.vertices) ** 2
+                                             for srv in self.servers))
+        return choose_layout(engine, shard_border, label_dtype,
+                             len(jax.devices()), 4 * btable.size,
+                             index_bytes, self._fitted_spec().lossless)
+
+    def _resolve_quant(self, label_dtype: str):
+        """The ``QuantSpec`` the engines pack with for a storage dtype
+        ``choose_layout`` resolved (None for float32 storage)."""
         if label_dtype == "float32":
             return None
-        btable = self.center.border_labels.table
-        locals_ = [srv.augmented for srv in self.servers]
-        if label_dtype in (None, "auto"):
-            est = 4 * (btable.size
-                       + sum(len(li.vertices) ** 2 for li in locals_))
-            if est <= QUANT_AUTO_BYTES:
-                return None
-            spec = fit_label_spec(btable, locals_)
-            return spec if spec.lossless else None
-        return fit_label_spec(btable, locals_,
+        if label_dtype == "uint16":
+            return self._fitted_spec()
+        from ..core.quantize import LABEL_DTYPES, fit_label_spec
+        return fit_label_spec(self.center.border_labels.table,
+                              [srv.augmented for srv in self.servers],
                               dtype=LABEL_DTYPES[label_dtype])
 
-    def _current_engine(self, prefer_sharded=_SELF, shard_border=_SELF,
-                        label_dtype=_SELF):
-        """Engine snapshot for the current index version, or None while
-        any district's shortcuts are stale (rebuild window). Single-device
-        backends get the replicated ``BatchedQueryEngine``; multi-device
-        backends shard the district tables over the ``edge`` mesh axis
-        (``ShardedBatchedEngine``) so the table scales past one device's
-        memory, and within the sharded engine B itself is row-sharded
-        once its replicated footprint crosses SHARD_BORDER_AUTO_BYTES.
-        ``label_dtype`` picks the storage dtype (see ``_resolve_quant``).
-        ``prefer_sharded`` / ``shard_border`` / ``label_dtype`` override
-        the auto choices (arguments take precedence over the instance
-        attributes; the request plane passes its ``ServingPolicy``
-        placement through them)."""
-        if prefer_sharded is _SELF:
-            prefer_sharded = self.prefer_sharded
-        if shard_border is _SELF:
-            shard_border = self.shard_border
-        if label_dtype is _SELF:
-            label_dtype = self.label_dtype
+    def _current_engine(self, policy: "ServingPolicy | None" = None):
+        """Engine snapshot for the current index version in the layout
+        ``choose_layout`` picks for ``policy``, or None while any
+        district's shortcuts are stale (rebuild window).  The replicated
+        layout is ``BatchedQueryEngine``; the sharded one is
+        ``ShardedBatchedEngine``, whose district tables are sharded over
+        the ``edge`` mesh axis so the table scales past one device's
+        memory, with B itself row-sharded where the layout says so.
+        One engine is cached, keyed on ``snapshot_key()`` and the
+        layout."""
         if any(srv.augmented is None
                or srv.augmented_version != self.center.version
                for srv in self.servers):
             return None
-        import jax
-        num_devices = len(jax.devices())
-        sharded = (num_devices > 1 if prefer_sharded is None
-                   else prefer_sharded)
-        btable = self.center.border_labels.table
-        shard_border = sharded and (
-            btable.size * 4 > SHARD_BORDER_AUTO_BYTES
-            if shard_border is None else shard_border)
-        # the placement maps districts to edge hosts; it becomes the
-        # device layout when the host and device counts line up (the
-        # simulator's one-host-per-device model), and joins the key
-        # either way so a migration always swaps the snapshot
-        placement = self.placement
-        pkey = None if placement is None else placement.key()
-        host_of = placement.host_of \
-            if placement is not None \
-            and placement.num_hosts == num_devices else None
-        key = (self.center.version,
-               tuple(srv.augmented_version for srv in self.servers),
-               sharded, shard_border, num_devices,
-               label_dtype or "auto", pkey)
+        layout = self.layout(policy)
+        key = (self.snapshot_key(), layout)
         if self._engine is None or self._engine_key != key:
+            import jax
+
             from .engine import BatchedQueryEngine, ShardedBatchedEngine
-            quant = self._resolve_quant(label_dtype)
+            quant = self._resolve_quant(layout.label_dtype)
             # drop the stale engine's device buffers BEFORE building the
             # replacement: holding both doubles peak device memory at
             # every rebuild, exactly where sharded tables run near limits
             # (for the sharded engines this swap also replaces the
             # device-resident B shards with the new version's)
             self._engine = None
-            if sharded:
+            btable = self.center.border_labels.table
+            locals_ = [srv.augmented for srv in self.servers]
+            if layout.sharded:
+                # the placement maps districts to edge hosts; it becomes
+                # the device layout when the host and device counts line
+                # up (the simulator's one-host-per-device model)
+                placement = self.placement
+                host_of = placement.host_of \
+                    if placement is not None \
+                    and placement.num_hosts == len(jax.devices()) else None
                 self._engine = ShardedBatchedEngine(
-                    btable, [srv.augmented for srv in self.servers],
-                    self.partition.assignment, shard_border=shard_border,
-                    quant=quant, placement=host_of)
+                    btable, locals_, self.partition.assignment,
+                    shard_border=layout.shard_border, quant=quant,
+                    placement=host_of)
             else:
                 self._engine = BatchedQueryEngine(
-                    btable, [srv.augmented for srv in self.servers],
-                    self.partition.assignment, quant=quant)
+                    btable, locals_, self.partition.assignment, quant=quant)
             self._engine_key = key
         return self._engine
 
-    def current_engine(self):
-        """Public accessor for the active serving-engine snapshot (None
-        during a rebuild window). Use this — not the underscore internals
-        — to inspect which layout the auto-pick chose and its
-        ``size_bytes()`` footprint."""
-        return self._current_engine()
-
-    def _current_scatter_plane(self, faults=None, label_dtype=_SELF):
-        """Scatter-gather coordinator plane for the current index
-        version, or None during a rebuild window (same freshness rule as
-        ``_current_engine``).  Building the plane pushes each server its
-        own district's B rows; peer exchanges then run lazily per batch
-        and persist on the servers across plane rebuilds of the same
-        version.  ``faults`` (an ``edge.faults.FaultPlan``) attaches a
-        deterministic injector; the plan is part of the cache key, so
-        switching plans rebuilds the plane (and its injector epoch).
-        ``label_dtype`` stores the plane's tables as quantized codes
-        exactly like the engines (see ``_resolve_quant``)."""
-        if label_dtype is _SELF:
-            label_dtype = self.label_dtype
-        if any(srv.augmented is None
-               or srv.augmented_version != self.center.version
-               for srv in self.servers):
-            return None
-        if faults is not None and not faults.enabled:
-            faults = None
-        pkey = None if self.placement is None else self.placement.key()
-        key = (self.center.version,
-               tuple(srv.augmented_version for srv in self.servers),
-               faults, label_dtype or "auto", pkey)
-        if self._scatter is None or self._scatter_key != key:
-            from .scatter_gather import ScatterGatherPlane
-            quant = self._resolve_quant(label_dtype)
-            self._scatter = None
-            self._scatter = ScatterGatherPlane.from_system(self,
-                                                           faults=faults,
-                                                           quant=quant)
-            self._scatter_key = key
-        return self._scatter
+    def current_engine(self, policy: "ServingPolicy | None" = None):
+        """Public accessor for the active serving-engine snapshot in
+        ``policy``'s layout (None during a rebuild window). Use this —
+        not the underscore internals — to inspect which layout the
+        auto-pick chose and its ``size_bytes()`` footprint."""
+        return self._current_engine(policy)
 
     def query_loop(self, ss: np.ndarray, ts: np.ndarray) -> np.ndarray:
         """Per-query Python reference path (parity + benchmark baseline);

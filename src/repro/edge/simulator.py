@@ -105,7 +105,6 @@ class SimResult:
     lb_certified_frac: float = 0.0
     waited_frac: float = 0.0
     stale_frac: float = 0.0     # served stale under the stale_ok policy
-    degraded_frac: float = 0.0  # flagged non-exact under injected faults
     # migration accounting (None / 0 unless a placement was simulated):
     # per-query masks for the exactness-outside-the-window assertion
     migration_stale_frac: float = 0.0   # flagged stale under "handoff"
@@ -115,14 +114,13 @@ class SimResult:
 
     @classmethod
     def from_latencies(cls, lat: np.ndarray, lb_frac=0.0, waited=0.0,
-                       stale=0.0, degraded=0.0):
+                       stale=0.0):
         if len(lat) == 0:       # empty trace: zeros, not NaN + warnings
             return cls(np.asarray(lat, dtype=np.float64), 0.0, 0.0, 0.0,
-                       0.0, lb_frac, waited, stale, degraded)
+                       0.0, lb_frac, waited, stale)
         return cls(lat, float(lat.mean()), float(np.percentile(lat, 50)),
                    float(np.percentile(lat, 95)),
-                   float(np.percentile(lat, 99)), lb_frac, waited, stale,
-                   degraded)
+                   float(np.percentile(lat, 99)), lb_frac, waited, stale)
 
     def row(self, name: str) -> dict:
         return {"system": name, "mean_ms": round(self.mean_ms, 3),
@@ -132,7 +130,6 @@ class SimResult:
                 "lb_certified": round(self.lb_certified_frac, 3),
                 "waited": round(self.waited_frac, 3),
                 "stale": round(self.stale_frac, 3),
-                "degraded": round(self.degraded_frac, 3),
                 "migration_stale": round(self.migration_stale_frac, 3)}
 
 
@@ -378,23 +375,13 @@ def simulate_centralized(trace: list[QueryEvent], topo: Topology,
     return SimResult.from_latencies(lat, waited=waited / max(1, len(trace)))
 
 
-def _resolve_injector(faults, policy):
-    """FaultInjector from an explicit plan or ``policy.faults`` (None
-    when nothing is enabled — the clean path stays untouched)."""
-    plan = faults if faults is not None else getattr(policy, "faults", None)
-    if plan is None or not getattr(plan, "enabled", False):
-        return None
-    from .faults import FaultInjector
-    return FaultInjector(plan)
-
-
 def simulate_edge(trace: list[QueryEvent], topo: Topology,
                   schedule: "UpdateSchedule | VariableUpdateSchedule",
                   assignment: np.ndarray,
                   certified_fn, num_districts: int,
                   batch: BatchPolicy | None = None,
                   policy: "ServingPolicy | None" = None,
-                  faults=None, placement=None,
+                  placement=None,
                   migrations=None) -> SimResult:
     """``certified_fn(s, t) -> bool`` — whether Theorem 3 certifies the
     local answer for a same-district pair (precomputed by the caller from
@@ -413,18 +400,7 @@ def simulate_edge(trace: list[QueryEvent], topo: Topology,
     window queries are answered from the stale index with no wait and
     counted in ``SimResult.stale_frac``; the ``install_now`` and
     ``certify_or_wait`` modes both charge the wait — functionally they
-    only differ in who pays for the install), and ``policy.engine ==
-    "scatter_gather"`` routes rule-3 queries to the SOURCE district's
-    edge server over the ``peer_edge_ms`` link (peer border-row
-    exchange) instead of forwarding through the center's WAN hops —
-    the center leaves the read path, so cross-district load also stops
-    queueing at one shared server.
-
-    ``faults`` (or ``policy.faults``) attaches a deterministic
-    ``edge.faults.FaultPlan``: dark servers reroute cross lanes to the
-    survivor, dead peer links are charged the retry/backoff budget then
-    forwarded through the center, and lanes that can only be served
-    stale/unavailable are counted in ``SimResult.degraded_frac``.
+    only differ in who pays for the install).
 
     ``placement`` (a ``repro.topo.EdgePlacement`` or a host_of array)
     consolidates the per-district queues onto shared edge *hosts* — the
@@ -438,10 +414,8 @@ def simulate_edge(trace: list[QueryEvent], topo: Topology,
     outside the declared window can be asserted.
     """
     stale_ok = policy is not None and policy.rebuild == "stale_ok"
-    scatter = policy is not None and policy.engine == "scatter_gather"
     handoff = (policy is not None
                and getattr(policy, "migration", "dual") == "handoff")
-    inj = _resolve_injector(faults, policy)
     if migrations and placement is None:
         raise ValueError("migrations require an explicit placement")
     tl = (_PlacementTimeline(placement, migrations)
@@ -451,8 +425,8 @@ def simulate_edge(trace: list[QueryEvent], topo: Topology,
     if batch is not None:
         return _simulate_edge_batched(trace, topo, schedule, assignment,
                                       certified_fn, num_districts, batch,
-                                      stale_ok=stale_ok, scatter=scatter,
-                                      inj=inj, tl=tl, handoff=handoff)
+                                      stale_ok=stale_ok, tl=tl,
+                                      handoff=handoff)
     edge_servers = [_Server(topo.latency.edge_service_ms)
                     for _ in range(tl.num_hosts if tl is not None
                                    else num_districts)]
@@ -461,7 +435,6 @@ def simulate_edge(trace: list[QueryEvent], topo: Topology,
     certified_n = 0
     waited = 0
     stale_n = 0
-    degraded_n = 0
     if tl is not None:
         hidx = tl.host_at
         win_mask = np.zeros(len(trace), dtype=bool)
@@ -483,27 +456,10 @@ def simulate_edge(trace: list[QueryEvent], topo: Topology,
 
     lm = topo.latency
     for i, ev in enumerate(trace):
-        if inj is not None:
-            inj.tick()
         ds, dt = int(assignment[ev.s]), int(assignment[ev.t])
         local_ready, global_ready = schedule.edge_windows(ev.t_ms)
         if ds == dt:
             arrive = ev.t_ms + lm.client_edge_ms
-            if inj is not None and inj.server_down(ds):
-                # dark district: the center's B join is a certified
-                # upper bound — served over the WAN, flagged degraded;
-                # with the center dark too, a flat flagged failure
-                degraded_n += 1
-                if nonexact is not None:
-                    nonexact[i] = True
-                if not inj.center_down():
-                    a = ev.t_ms + lm.client_edge_ms + lm.edge_center_ms
-                    done = center.serve(a)
-                    lat[i] = done + lm.edge_center_ms + lm.client_edge_ms \
-                        - ev.t_ms
-                else:
-                    lat[i] = 2 * lm.client_edge_ms
-                continue
             if arrive >= global_ready:          # L_i⁺ fresh: exact at edge
                 _mark(i, ds, ev.t_ms)
                 done = edge_servers[hidx(ds, ev.t_ms)].serve(arrive)
@@ -530,72 +486,6 @@ def simulate_edge(trace: list[QueryEvent], topo: Topology,
             done = edge_servers[hidx(ds, ev.t_ms)].serve(
                 max(arrive, global_ready))
             lat[i] = done + lm.client_edge_ms - ev.t_ms
-        elif scatter:
-            # peer border-row exchange: one metro hop to fetch B[t] from
-            # the target district's server, answered at the OWN server
-            # (exchanged rows come from the same B rebuild, so the
-            # freshness window is unchanged)
-            arrive = ev.t_ms + lm.client_edge_ms + lm.peer_edge_ms
-            if arrive < global_ready:
-                if stale_ok:
-                    stale_n += 1
-                    if nonexact is not None:
-                        nonexact[i] = True
-                else:
-                    waited += 1
-                    arrive = global_ready
-            if inj is None:
-                _mark(i, ds, ev.t_ms)
-                done = edge_servers[hidx(ds, ev.t_ms)].serve(arrive)
-                lat[i] = done + lm.peer_edge_ms + lm.client_edge_ms \
-                    - ev.t_ms
-                continue
-            src_dark = inj.server_down(ds)
-            if src_dark and not inj.server_down(dt):
-                # rule 3 from the surviving min: the target district's
-                # server owns the lane — exact, same peer math
-                _mark(i, dt, ev.t_ms)
-                done = edge_servers[hidx(dt, ev.t_ms)].serve(arrive)
-                lat[i] = done + lm.peer_edge_ms + lm.client_edge_ms \
-                    - ev.t_ms
-                continue
-            if src_dark:                        # both districts dark
-                if not inj.center_down():       # forwarded: still exact
-                    a = arrive - lm.peer_edge_ms + lm.edge_center_ms
-                    done = center.serve(a)
-                    lat[i] = done + lm.edge_center_ms + lm.client_edge_ms \
-                        - ev.t_ms
-                else:                           # flagged unavailable
-                    degraded_n += 1
-                    if nonexact is not None:
-                        nonexact[i] = True
-                    lat[i] = 2 * lm.client_edge_ms
-                continue
-            ok, fault, charged, slow = inj.link_trial(ds, dt)
-            if ok:
-                if slow:                        # degraded (slow) link
-                    charged += (inj.plan.slow_factor - 1) * lm.peer_edge_ms
-                _mark(i, ds, ev.t_ms)
-                done = edge_servers[hidx(ds, ev.t_ms)].serve(
-                    arrive + charged)
-                lat[i] = done + lm.peer_edge_ms + lm.client_edge_ms \
-                    - ev.t_ms
-            elif not inj.center_down():
-                # peer link dead: forwarded-path fallback, still exact
-                a = arrive - lm.peer_edge_ms + charged + lm.edge_center_ms
-                done = center.serve(a)
-                lat[i] = done + lm.edge_center_ms + lm.client_edge_ms \
-                    - ev.t_ms
-            else:
-                # stale previous-generation rows (or flagged +inf),
-                # served locally after the failed retries
-                degraded_n += 1
-                if nonexact is not None:
-                    nonexact[i] = True
-                _mark(i, ds, ev.t_ms)
-                done = edge_servers[hidx(ds, ev.t_ms)].serve(
-                    arrive - lm.peer_edge_ms + charged)
-                lat[i] = done + lm.client_edge_ms - ev.t_ms
         else:
             arrive = ev.t_ms + lm.client_edge_ms + lm.edge_center_ms
             if arrive < global_ready:
@@ -606,24 +496,12 @@ def simulate_edge(trace: list[QueryEvent], topo: Topology,
                 else:
                     waited += 1
                     arrive = global_ready
-            if inj is not None and inj.center_down():
-                # forwarded path with the center dark: flagged local
-                # stale serve instead of an error
-                degraded_n += 1
-                if nonexact is not None:
-                    nonexact[i] = True
-                _mark(i, ds, ev.t_ms)
-                a = ev.t_ms + lm.client_edge_ms
-                done = edge_servers[hidx(ds, ev.t_ms)].serve(a)
-                lat[i] = done + lm.client_edge_ms - ev.t_ms
-                continue
             done = center.serve(arrive)
             lat[i] = done + lm.edge_center_ms + lm.client_edge_ms - ev.t_ms
     res = SimResult.from_latencies(
         lat, lb_frac=certified_n / max(1, len(trace)),
         waited=waited / max(1, len(trace)),
-        stale=stale_n / max(1, len(trace)),
-        degraded=degraded_n / max(1, len(trace)))
+        stale=stale_n / max(1, len(trace)))
     if tl is not None:
         res.migration_window_mask = win_mask
         res.nonexact_mask = nonexact
@@ -635,18 +513,13 @@ def _simulate_edge_batched(trace: list[QueryEvent], topo: Topology,
                            schedule: UpdateSchedule, assignment: np.ndarray,
                            certified_fn, num_districts: int,
                            batch: BatchPolicy,
-                           stale_ok: bool = False,
-                           scatter: bool = False,
-                           inj=None, tl=None,
+                           stale_ok: bool = False, tl=None,
                            handoff: bool = False) -> SimResult:
     """§4.2 routing with micro-batched service at every server: same
     freshness rules as the per-query path, but departures are assigned at
-    batch flush time (see _BatchedServer).  ``scatter`` routes rule-3
-    lanes to the source district's server over the peer link; ``inj``
-    (a ``FaultInjector``) applies the same degradation ladder as the
-    per-query path; ``tl`` (a ``_PlacementTimeline``) consolidates the
-    queues onto edge hosts and applies the migration schedule (see
-    simulate_edge)."""
+    batch flush time (see _BatchedServer).  ``tl`` (a
+    ``_PlacementTimeline``) consolidates the queues onto edge hosts and
+    applies the migration schedule (see simulate_edge)."""
     edge_servers = [_BatchedServer(batch)
                     for _ in range(tl.num_hosts if tl is not None
                                    else num_districts)]
@@ -656,7 +529,6 @@ def _simulate_edge_batched(trace: list[QueryEvent], topo: Topology,
     certified_n = 0
     waited = 0
     stale_n = 0
-    degraded_n = 0
     if tl is not None:
         hidx = tl.host_at
         win_mask = np.zeros(len(trace), dtype=bool)
@@ -676,24 +548,11 @@ def _simulate_edge_batched(trace: list[QueryEvent], topo: Topology,
 
     lm = topo.latency
     for i, ev in enumerate(trace):
-        if inj is not None:
-            inj.tick()
         ds, dt = int(assignment[ev.s]), int(assignment[ev.t])
         local_ready, global_ready = schedule.edge_windows(ev.t_ms)
         if ds == dt:
             arrive = ev.t_ms + lm.client_edge_ms
             back_ms[i] = lm.client_edge_ms
-            if inj is not None and inj.server_down(ds):
-                degraded_n += 1     # dark district: center upper bound
-                if nonexact is not None:
-                    nonexact[i] = True
-                if not inj.center_down():
-                    back_ms[i] = lm.edge_center_ms + lm.client_edge_ms
-                    center.submit(i, arrive + lm.edge_center_ms,
-                                  departures)
-                else:               # flat flagged failure, no service
-                    departures[i] = arrive
-                continue
             if arrive >= global_ready:          # L_i⁺ fresh: exact at edge
                 _mark(i, ds, ev.t_ms)
                 edge_servers[hidx(ds, ev.t_ms)].submit(i, arrive,
@@ -718,59 +577,6 @@ def _simulate_edge_batched(trace: list[QueryEvent], topo: Topology,
             _mark(i, ds, ev.t_ms)
             edge_servers[hidx(ds, ev.t_ms)].submit(
                 i, max(arrive, global_ready), departures)
-        elif scatter:
-            arrive = ev.t_ms + lm.client_edge_ms + lm.peer_edge_ms
-            back_ms[i] = lm.peer_edge_ms + lm.client_edge_ms
-            if arrive < global_ready:
-                if stale_ok:
-                    stale_n += 1
-                    if nonexact is not None:
-                        nonexact[i] = True
-                else:
-                    waited += 1
-                    arrive = global_ready
-            if inj is None:
-                _mark(i, ds, ev.t_ms)
-                edge_servers[hidx(ds, ev.t_ms)].submit(i, arrive,
-                                                       departures)
-                continue
-            src_dark = inj.server_down(ds)
-            if src_dark and not inj.server_down(dt):
-                # surviving-min reroute: target server, same peer math
-                _mark(i, dt, ev.t_ms)
-                edge_servers[hidx(dt, ev.t_ms)].submit(i, arrive,
-                                                       departures)
-                continue
-            if src_dark:                        # both districts dark
-                if not inj.center_down():
-                    back_ms[i] = lm.edge_center_ms + lm.client_edge_ms
-                    center.submit(i, arrive - lm.peer_edge_ms
-                                  + lm.edge_center_ms, departures)
-                else:
-                    degraded_n += 1
-                    if nonexact is not None:
-                        nonexact[i] = True
-                    back_ms[i] = lm.client_edge_ms
-                    departures[i] = ev.t_ms + lm.client_edge_ms
-                continue
-            ok, fault, charged, slow = inj.link_trial(ds, dt)
-            if ok:
-                if slow:
-                    charged += (inj.plan.slow_factor - 1) * lm.peer_edge_ms
-                _mark(i, ds, ev.t_ms)
-                edge_servers[hidx(ds, ev.t_ms)].submit(i, arrive + charged,
-                                                       departures)
-            elif not inj.center_down():         # forwarded: still exact
-                back_ms[i] = lm.edge_center_ms + lm.client_edge_ms
-                center.submit(i, arrive - lm.peer_edge_ms + charged
-                              + lm.edge_center_ms, departures)
-            else:                               # local stale, flagged
-                degraded_n += 1
-                if nonexact is not None:
-                    nonexact[i] = True
-                _mark(i, ds, ev.t_ms)
-                edge_servers[hidx(ds, ev.t_ms)].submit(
-                    i, arrive - lm.peer_edge_ms + charged, departures)
         else:
             arrive = ev.t_ms + lm.client_edge_ms + lm.edge_center_ms
             back_ms[i] = lm.edge_center_ms + lm.client_edge_ms
@@ -782,15 +588,6 @@ def _simulate_edge_batched(trace: list[QueryEvent], topo: Topology,
                 else:
                     waited += 1
                     arrive = global_ready
-            if inj is not None and inj.center_down():
-                degraded_n += 1     # center dark: flagged local serve
-                if nonexact is not None:
-                    nonexact[i] = True
-                _mark(i, ds, ev.t_ms)
-                back_ms[i] = lm.client_edge_ms
-                edge_servers[hidx(ds, ev.t_ms)].submit(
-                    i, ev.t_ms + lm.client_edge_ms, departures)
-                continue
             center.submit(i, arrive, departures)
     for srv in edge_servers:
         srv.finish(departures)
@@ -799,8 +596,7 @@ def _simulate_edge_batched(trace: list[QueryEvent], topo: Topology,
     res = SimResult.from_latencies(
         lat, lb_frac=certified_n / max(1, len(trace)),
         waited=waited / max(1, len(trace)),
-        stale=stale_n / max(1, len(trace)),
-        degraded=degraded_n / max(1, len(trace)))
+        stale=stale_n / max(1, len(trace)))
     if tl is not None:
         res.migration_window_mask = win_mask
         res.nonexact_mask = nonexact

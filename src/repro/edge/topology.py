@@ -2,10 +2,8 @@
 
 Latency constants model the three-layer architecture: clients reach their
 district's edge server over 5G; edge servers reach the cloud computing
-center over the WAN, and neighboring edge servers reach each other over a
-metro peer link (the scatter-gather read path — cross-district queries
-answered edge-side never touch the WAN). The centralized baseline routes
-every query from the client straight to the cloud.
+center over the WAN. The centralized baseline routes every query from the
+client straight to the cloud.
 """
 from __future__ import annotations
 
@@ -18,7 +16,6 @@ class LatencyModel:
     client_edge_ms: float = 5.0       # 5G hop (§4.1)
     edge_center_ms: float = 30.0      # WAN hop
     client_center_ms: float = 35.0    # centralized baseline path
-    peer_edge_ms: float = 8.0         # edge ↔ edge metro peer link
 
     # service times (per query, ms) — calibrated from the measured label
     # join costs; HL-based queries are microsecond-level (§5.1), so the
@@ -44,13 +41,6 @@ class Topology:
     def center_rtt_ms(self) -> float:
         return 2 * (self.latency.client_edge_ms
                     + self.latency.edge_center_ms)
-
-    def peer_rtt_ms(self) -> float:
-        # client → own edge → peer edge hop amortized into the exchange;
-        # the answer is consolidated at the client's own edge server, so
-        # the round trip pays one peer hop each way instead of two WAN hops
-        return 2 * (self.latency.client_edge_ms
-                    + self.latency.peer_edge_ms)
 
     def centralized_rtt_ms(self) -> float:
         return 2 * self.latency.client_center_ms

@@ -28,12 +28,6 @@ Paper map (anchors refer to PAPER.md / the source paper):
   ``quant=`` kwarg threads the same through both sharded entry points;
   in the B-sharded ragged assembly the cross-device ``pmin`` runs on the
   codes widened to int32 (the sentinel doubles as the min identity).
-* ``join_partial_gathered`` — the per-edge-server half of the scatter-
-  gather read path (``edge/scatter_gather.py``): one server's min-plus
-  partial over pre-assembled label rows (its own district block plus
-  peer-exchanged border rows). The coordinator consolidates the
-  per-server partials with one host-side min — MIN-of-MINs, the
-  distance analogue of EdgeLake's remote/local query rewriting.
 """
 from __future__ import annotations
 
@@ -156,26 +150,6 @@ def join_quantized_gathered(table: np.ndarray, ss: np.ndarray,
     out = join_quantized(jnp.asarray(s_rows), jnp.asarray(t_rows),
                          sentinel=sentinel, scale=scale,
                          use_pallas=use_pallas)
-    return np.asarray(out)[:qn]
-
-
-def join_partial_gathered(s_rows: np.ndarray, t_rows: np.ndarray, *,
-                          use_pallas: bool = True) -> np.ndarray:
-    """One edge server's scatter-gather partial: a dense 2-hop join over
-    label rows the caller already assembled (district block rows for the
-    server's local lanes, own/peer border rows for its cross lanes).
-    Same kernel, same PAD_Q batch bucketing, and the same inf-padding
-    convention as the engine joins — a lane's answer depends only on its
-    own two rows, so the partial is bit-for-bit the lane's value in the
-    sharded engine's pre-``pmin`` per-device vector."""
-    qn = len(s_rows)
-    if qn == 0 or s_rows.shape[1] == 0:
-        return np.full(qn, np.inf, dtype=np.float32)
-    qp = _ceil_to(qn, PAD_Q)
-    sp = np.full((qp, s_rows.shape[1]), np.inf, dtype=np.float32)
-    tp = np.full((qp, t_rows.shape[1]), np.inf, dtype=np.float32)
-    sp[:qn], tp[:qn] = s_rows, t_rows
-    out = join(jnp.asarray(sp), jnp.asarray(tp), use_pallas=use_pallas)
     return np.asarray(out)[:qn]
 
 

@@ -22,9 +22,7 @@ The harness
   service time.  Queue-delay-inclusive latency per request is
   ``batch_departure − arrival + network RTT``, with the RTT drawn from
   the §4.1 ``Topology`` helpers (``request_rtt_ms``): cross-district
-  requests pay the two-WAN-hop forwarded round trip — or only the
-  metro peer link when the service's policy selects the scatter-gather
-  plane;
+  requests pay the two-WAN-hop forwarded round trip;
 * sheds load under overload when ``max_queue`` is set: an arrival that
   finds that many requests already waiting is dropped (the bounded-
   queue drop policy — goodput holds at capacity while p99 of admitted
@@ -56,19 +54,13 @@ if TYPE_CHECKING:                                   # pragma: no cover
     from .service import DistanceService
 
 
-def request_rtt_ms(topo: Topology, cross: np.ndarray,
-                   scatter: bool = False) -> np.ndarray:
+def request_rtt_ms(topo: Topology, cross: np.ndarray) -> np.ndarray:
     """Per-request network RTT from the §4.1 ``Topology`` helpers:
     same-district requests pay the 5G edge round trip; cross-district
     requests pay two WAN hops through the center's forwarding agent
-    (``forward_rtt_ms``) — or only the metro peer link
-    (``peer_rtt_ms``) when the scatter-gather plane answers them
-    edge-side.  All RTT math routes through here so a new path slots in
-    uniformly (the old inline constants under-charged the forwarded
-    path by one WAN round trip)."""
-    cross_rtt = topo.peer_rtt_ms() if scatter else topo.forward_rtt_ms()
+    (``forward_rtt_ms``)."""
     return np.where(np.asarray(cross, dtype=bool),
-                    cross_rtt, topo.edge_rtt_ms())
+                    topo.forward_rtt_ms(), topo.edge_rtt_ms())
 
 
 def open_rebuild_window(system: "EdgeSystem",
@@ -117,7 +109,6 @@ class LoadReport:
     queue_peak: int
     engine_calls: int
     mean_batch_service_ms: float
-    degraded_frac: float = 0.0      # of admitted (fault-flagged answers)
     latencies_ms: np.ndarray = field(default=None, repr=False)
     # (m,) int64 answered queries per source district — the load signal
     # repro.topo.RebalancePlanner.observe_load consumes
@@ -214,17 +205,7 @@ class OpenLoopLoadGen:
         assignment = system.partition.assignment
         cross = assignment[ss] != assignment[ts]
         topo = Topology(system.partition.num_districts, self.latency)
-        scatter = self.service.policy.engine == "scatter_gather"
-        fault_plan = getattr(self.service.policy, "faults", None)
-        degraded = np.zeros(offered, dtype=bool)
-        if scatter and fault_plan is not None:
-            # fault-aware network view: failed/slow links, reroutes, and
-            # the lanes that can only be answered degraded (flagged)
-            from ..edge.faults import loadgen_network_model
-            rtt, degraded, _fault_info = loadgen_network_model(
-                fault_plan, topo, assignment[ss], assignment[ts], cross)
-        else:
-            rtt = request_rtt_ms(topo, cross, scatter=scatter)
+        rtt = request_rtt_ms(topo, cross)
 
         update_at_ms = (None if update_at_frac is None
                         else float(update_at_frac) * horizon_ms)
@@ -332,7 +313,6 @@ class OpenLoopLoadGen:
             p999_ms=float(p999), max_ms=mx, queue_peak=queue_peak,
             engine_calls=engine_calls,
             mean_batch_service_ms=service_ms_total / max(1, engine_calls),
-            degraded_frac=int(degraded[~shed].sum()) / max(1, admitted),
             latencies_ms=lat,
             district_load=np.bincount(
                 assignment[ss[~shed]],
@@ -352,7 +332,6 @@ class OpenLoopLoadGen:
         n_vertices = int(system.graph.num_vertices)
         assignment = system.partition.assignment
         topo = Topology(system.partition.num_districts, self.latency)
-        scatter = self.service.policy.engine == "scatter_gather"
         n_closed = int(self.closed_loop)
         target_qps = num_clients * per_client_qps
         if target_qps <= 0:
@@ -409,8 +388,7 @@ class OpenLoopLoadGen:
             certified_n += int((codes == np.uint8(1)).sum())
             for j in pending:
                 cross = assignment[req_ss[j]] != assignment[req_ts[j]]
-                rtt = float(request_rtt_ms(topo, np.array([cross]),
-                                           scatter=scatter)[0])
+                rtt = float(request_rtt_ms(topo, np.array([cross]))[0])
                 req_lat[j] = done - req_arr[j] + rtt
                 # the answer lands at the client after the return hop;
                 # it thinks, then issues the next query

@@ -8,11 +8,11 @@ versions behind one interface.  This module is that interface:
   A result carries the distance, the §4.2 rule it was served under, an
   exactness flag (``exact`` | ``certified_stale`` | ``stale``), the
   index version that answered it, and the dispatch latency.
-* ``ServingPolicy`` — one config object for the knobs that used to be
-  scattered over ``EdgeSystem`` attributes and keyword arguments:
-  engine placement (``auto``/``replicated``/``sharded`` +
-  ``shard_border``), kernel use, micro-batching (a simulator
-  ``BatchPolicy``), and the rebuild-window mode.
+* ``ServingPolicy`` — one config object for the serving knobs: engine
+  placement (``auto``/``replicated``/``sharded`` + ``shard_border`` +
+  ``label_dtype``, resolved by ``edge.router.choose_layout``), kernel
+  use, micro-batching (a simulator ``BatchPolicy``), and the
+  rebuild-window mode.
 * ``QueryPlane`` — the protocol every execution backend implements
   (``execute(ss, ts) -> distances``): the steady-state
   ``BatchedQueryEngine`` / ``ShardedBatchedEngine`` snapshots, the
@@ -59,7 +59,6 @@ from .. import obs
 from ..core.query import Rule, bucket_by_rule, route
 
 if TYPE_CHECKING:                                   # pragma: no cover
-    from ..edge.faults import FaultPlan
     from ..edge.router import EdgeSystem
     from ..edge.simulator import BatchPolicy
     from .distance_batcher import DistanceBatcher
@@ -83,7 +82,7 @@ MIGRATION_DUAL = "dual"
 MIGRATION_HANDOFF = "handoff"
 MIGRATION_MODES = (MIGRATION_DUAL, MIGRATION_HANDOFF)
 
-ENGINE_PLACEMENTS = ("auto", "replicated", "sharded", "scatter_gather")
+ENGINE_PLACEMENTS = ("auto", "replicated", "sharded")
 LABEL_DTYPE_CHOICES = ("auto", "float32", "uint16", "int16")
 
 _COUNTER_KEYS = ("rule1", "rule2", "rule3", "lb_certified",
@@ -98,25 +97,18 @@ def _fresh_counters() -> dict[str, int]:
 class ServingPolicy:
     """Every serving knob in one immutable config object.
 
-    ``engine`` picks the steady-state plane placement: ``"auto"``
-    (defer to the system's override attributes, then the device-count
-    heuristic), ``"replicated"``, ``"sharded"``, or ``"scatter_gather"``
-    (the coordinator plane of ``edge.scatter_gather`` — cross-district
-    lanes answered edge-side via peer border-row exchange, bit-for-bit
-    with the engines).  ``shard_border``
-    picks the border-table placement inside the sharded engine (None =
-    defer to the system override / byte-size heuristic).  ``batch``
+    ``engine`` picks the steady-state engine: ``"auto"`` (sharded iff
+    the backend has more than one device), ``"replicated"`` or
+    ``"sharded"``.  ``shard_border`` picks the border-table placement
+    inside the sharded engine (None = by B's bytes).  ``label_dtype``
+    picks the label-storage dtype: ``"auto"`` (uint16 once the index is
+    large, and only when the fit is lossless, so auto never changes an
+    answer), ``"float32"``, ``"uint16"``, or ``"int16"`` (explicit
+    integer dtypes are honored even when the fit is lossy).  The three
+    are resolved together by ``edge.router.choose_layout``.  ``batch``
     carries the micro-batching discipline (a simulator ``BatchPolicy``)
     for ``DistanceService.batcher`` and ``simulate_edge(policy=...)``.
     ``rebuild`` is the rebuild-window mode (see module docstring).
-    ``faults`` attaches a deterministic ``edge.faults.FaultPlan`` to the
-    scatter-gather plane (degrade-never-error discipline; a disabled
-    plan is normalized to None so it cannot perturb the clean path).
-    ``label_dtype`` picks the label-storage dtype: ``"auto"`` (defer to
-    the system attribute, then the byte-size heuristic — quantize to
-    uint16 only when the fit is lossless, so auto never changes an
-    answer), ``"float32"``, ``"uint16"``, or ``"int16"`` (explicit
-    integer dtypes are honored even when the fit is lossy).
     ``migration`` is the district-migration window discipline for the
     §5 simulator: ``"dual"`` (the source host keeps serving the moving
     district exactly until the routing swap lands — no staleness,
@@ -129,7 +121,6 @@ class ServingPolicy:
     use_kernels: bool = True
     rebuild: str = INSTALL_NOW
     batch: "BatchPolicy | None" = None
-    faults: "FaultPlan | None" = None
     label_dtype: str = "auto"
     migration: str = "dual"
 
@@ -147,8 +138,6 @@ class ServingPolicy:
             raise ValueError(
                 f"label_dtype must be one of {LABEL_DTYPE_CHOICES}, "
                 f"got {self.label_dtype!r}")
-        if self.faults is not None and not self.faults.enabled:
-            object.__setattr__(self, "faults", None)
 
 
 @dataclass(frozen=True)
@@ -170,11 +159,6 @@ class QueryResult:
     index_version: int
     latency_s: float
     waited: bool = False    # deferred to the shortcut push mid-window
-    # why (and how) the answer degraded under injected faults, e.g.
-    # "peer_drop:forwarded_via_center"; None on the clean path.  A set
-    # reason with exactness == "exact" means the fallback route itself
-    # is exact (center forwarding, surviving-min reroute).
-    degraded_reason: str | None = None
 
     @property
     def exact(self) -> bool:
@@ -209,7 +193,6 @@ class ResultBatch:
     _fallback: np.ndarray | None = None  # (B,) bool — plain-L_i Thm-3 path
     _waited: np.ndarray | None = None   # (B,) bool — deferred to the push
     real: np.ndarray | None = None      # (B,) bool — False for padding
-    _degraded: np.ndarray | None = None  # (B,) object — fault reasons
     _ds: np.ndarray | None = None       # (B,) int32 source districts
 
     def __len__(self) -> int:
@@ -255,19 +238,11 @@ class ResultBatch:
             self._waited = np.zeros(len(self.distances), dtype=bool)
         return self._waited
 
-    @property
-    def degraded_reason(self) -> np.ndarray:
-        if self._degraded is None:
-            self._degraded = np.full(len(self.distances), None,
-                                     dtype=object)
-        return self._degraded
-
     def __getitem__(self, i: int) -> QueryResult:
         return QueryResult(float(self.distances[i]), Rule(int(self.rules[i])),
                            _EXACTNESS[int(self.exactness_codes[i])],
                            self.index_version, self.latency_s,
-                           bool(self.waited[i]),
-                           self.degraded_reason[i])
+                           bool(self.waited[i]))
 
     def to_list(self) -> list[QueryResult]:
         return [self[i] for i in range(len(self))]
@@ -422,13 +397,11 @@ class QueryPlan:
                           dtype=np.float32)
         latency = time.perf_counter() - t0
         # per-batch metadata is plane-published: the BucketedPlane sets
-        # all three window arrays, the scatter plane sets exactness +
-        # degraded reasons after a faulted batch, and the steady-state
-        # engines have none of the attributes (None ⇒ lazily all-exact)
+        # all three window arrays, and the steady-state engines have none
+        # of the attributes (None ⇒ lazily all-exact)
         codes = getattr(self.plane, "exactness_codes", None)
         fallback = getattr(self.plane, "fallback", None)
         waited = getattr(self.plane, "waited", None)
-        degraded = getattr(self.plane, "degraded", None)
         with obs.span("repro.wrap"):
             if real is not None:
                 real = np.asarray(real, dtype=bool)
@@ -436,7 +409,7 @@ class QueryPlan:
                 dist, self.service.index_version, latency,
                 (self.service.system.partition.assignment, self.ss, self.ts,
                  self.client_districts),
-                None, codes, fallback, waited, real, degraded)
+                None, codes, fallback, waited, real)
             self.service._enqueue(batch)
         return batch
 
@@ -467,11 +440,11 @@ class DistanceService:
         self._district_load = np.zeros(system.partition.num_districts,
                                        dtype=np.int64)
         self._pending: list[ResultBatch] = []
-        # (resolution key, engine) — avoids re-walking the router's
-        # engine-selection logic on every submit; the key captures
-        # everything the selection reads (freshness itself is re-checked
-        # in plan() each call)
-        self._plane_cache: tuple | None = None
+        # (the router's snapshot key, the engine it returned): a submit
+        # reuses the engine while the index version and the placement
+        # hold and the router still serves it (freshness itself is
+        # re-checked in plan() each call)
+        self._engine_memo: tuple | None = None
 
     # -- introspection ------------------------------------------------------
 
@@ -515,37 +488,24 @@ class DistanceService:
     # -- planning -----------------------------------------------------------
 
     def _resolve_engine(self):
-        """Steady-state engine snapshot per the policy placement (None
-        when kernels are off; only called once ``plan`` verified the
-        window is closed, i.e. every server is at the center's
-        version)."""
-        p = self.policy
-        if not p.use_kernels:
+        """Steady-state engine snapshot for the policy (None when kernels
+        are off; only called once ``plan`` verified the window is
+        closed, i.e. every server is at the center's version).  The
+        router decides the layout and holds the engine cache; this only
+        asks it again when the snapshot key moved or the router dropped
+        the engine."""
+        if not self.policy.use_kernels:
             return None
-        dtype = (self.system.label_dtype if p.label_dtype == "auto"
-                 else p.label_dtype)
-        placement = getattr(self.system, "placement", None)
-        key = (self.system.center.version, p.engine, p.shard_border,
-               self.system.prefer_sharded, self.system.shard_border,
-               p.faults, dtype or "auto",
-               placement.key() if placement is not None else None)
-        if self._plane_cache is not None and self._plane_cache[0] == key:
-            return self._plane_cache[1]
+        system = self.system
+        memo = self._engine_memo
+        if memo is not None and memo[1] is system._engine \
+                and memo[0] == system.snapshot_key():
+            return memo[1]
         obs.count("serve.engine_builds")
         with obs.span("repro.engine.build"):
-            if p.engine == "scatter_gather":
-                engine = self.system._current_scatter_plane(
-                    faults=p.faults, label_dtype=dtype)
-            else:
-                prefer = {"auto": self.system.prefer_sharded,
-                          "replicated": False, "sharded": True}[p.engine]
-                border = (self.system.shard_border if p.shard_border is None
-                          else p.shard_border)
-                engine = self.system._current_engine(prefer_sharded=prefer,
-                                                     shard_border=border,
-                                                     label_dtype=dtype)
+            engine = system._current_engine(self.policy)
         if engine is not None:
-            self._plane_cache = (key, engine)
+            self._engine_memo = (system._engine_key[0], engine)
         return engine
 
     def plan(self, ss: np.ndarray, ts: np.ndarray,

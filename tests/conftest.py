@@ -1,9 +1,8 @@
 """Shared session-scoped fixtures.
 
-``test_service`` / ``test_border_sharding`` / ``test_scatter_gather``
-each used to build the SAME (10×10, 8-district) graph and deploy an
-``EdgeSystem`` over it — three deploys of identical state per tier-1
-run; ``test_query_engine`` did the same with the smaller (8×8,
+``test_service`` / ``test_border_sharding`` each used to build the
+SAME (10×10, 8-district) graph and deploy an ``EdgeSystem`` over it —
+two deploys of identical state per tier-1 run; ``test_query_engine`` did the same with the smaller (8×8,
 4-district) case.  These fixtures build each once per session.
 
 The deployed systems are READ-ONLY: every test that mutates serving

@@ -97,6 +97,12 @@ def test_border_sharded_engine_parity(system):
     np.testing.assert_array_equal(sharded.query(ss, ts), loop)
     np.testing.assert_array_equal(border.query(ss, ts), loop)
     assert (loop[::17] == 0.0).all()
+    # single-lane batches: an s == t lane, a border endpoint, a cross lane
+    cross = np.nonzero(part.assignment[ss] != part.assignment[ts])[0]
+    for i in (0, 1, int(cross[0])):
+        for eng in (replicated, sharded, border):
+            np.testing.assert_array_equal(
+                eng.query(ss[i:i + 1], ts[i:i + 1]), loop[i:i + 1])
 
 
 def test_border_sharded_footprint_formulas(system):
@@ -128,31 +134,27 @@ def test_router_shard_border_override_and_auto(system):
     rng = np.random.default_rng(9)
     ss, ts = _mixed_batch(g, sys_, rng, size=300)
     loop = sys_.query_loop(ss, ts)
+    from repro.serve import ServingPolicy
+    forced = ServingPolicy(engine="sharded", shard_border=True)
+    auto = ServingPolicy(engine="sharded")
     try:
-        sys_.prefer_sharded = True
-        sys_.shard_border = True
         np.testing.assert_array_equal(
-            sys_.service().submit(ss, ts).distances, loop)
-        eng = sys_._current_engine()
+            sys_.service(forced).submit(ss, ts).distances, loop)
+        eng = sys_._current_engine(forced)
         assert isinstance(eng, ShardedBatchedEngine) and eng.shard_border
         # auto heuristic: a toy B is far below SHARD_BORDER_AUTO_BYTES,
         # so None must resolve to the replicated-B sharded engine
-        sys_.shard_border = None
         np.testing.assert_array_equal(
-            sys_.service().submit(ss, ts).distances, loop)
-        eng = sys_._current_engine()
+            sys_.service(auto).submit(ss, ts).distances, loop)
+        eng = sys_._current_engine(auto)
         assert isinstance(eng, ShardedBatchedEngine)
         assert not eng.shard_border
-        # ServingPolicy placement overrides beat the system attributes
-        from repro.serve import ServingPolicy
-        svc = sys_.service(ServingPolicy(engine="sharded",
-                                         shard_border=True))
+        # the service plans onto the engine of its own policy's layout
+        svc = sys_.service(forced)
         np.testing.assert_array_equal(svc.submit(ss, ts).distances, loop)
         eng = svc.plan(ss, ts).plane
         assert isinstance(eng, ShardedBatchedEngine) and eng.shard_border
     finally:
-        sys_.prefer_sharded = None
-        sys_.shard_border = None
         sys_._engine = sys_._engine_key = None
 
 

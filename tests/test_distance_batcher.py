@@ -8,10 +8,10 @@ import numpy as np
 
 from repro.configs.base import get_smoke_config
 from repro.core import bfs_grow_partition, grid_road_network
-from repro.edge import EdgeSystem, FaultPlan
+from repro.edge import EdgeSystem
 from repro.models.lm import init_params
 from repro.serve import (BatchedDecoder, DistanceBatcher, DistanceRequest,
-                         Request, ServingPolicy)
+                         Request)
 
 
 def _echo_engine(calls):
@@ -222,29 +222,6 @@ def test_distance_batcher_all_padding_mask_skips_counters():
     out = svc.submit(zeros, zeros, real=np.zeros(8, dtype=bool))
     np.testing.assert_array_equal(out.distances, np.zeros(8, np.float32))
     assert sum(svc.stats[k] for k in ("rule1", "rule2", "rule3")) == 0
-
-
-def test_distance_batcher_faulted_service_flags_not_errors():
-    """Chaos meets the front door: a blackout FaultPlan behind the
-    batcher degrades answers (flagged by the service) but every real
-    request still completes — the batcher never sees an exception and
-    padding dummies stay invisible."""
-    g = grid_road_network(6, 6, seed=3)
-    part = bfs_grow_partition(g, 2, seed=1)
-    sys_ = EdgeSystem.deploy(g, part)
-    svc = sys_.service(ServingPolicy(
-        engine="scatter_gather",
-        faults=FaultPlan(seed=3, peer_drop_rate=1.0, center_down=True)))
-    b = DistanceBatcher(svc, batch_size=4)
-    b.submit_pairs([(i, g.num_vertices - 1 - i) for i in range(6)])
-    done = b.run()
-    assert len(done) == 6 and all(r.rid >= 0 for r in b.completed)
-    cross = part.assignment[[r.s for r in done]] \
-        != part.assignment[[r.t for r in done]]
-    assert cross.any()
-    dists = np.array([r.distance for r in done])
-    assert np.isinf(dists[cross]).all()       # degraded: flagged +inf
-    assert np.isfinite(dists[~cross]).all()   # local lanes stay exact
 
 
 def test_decoder_empty_queue_and_padding():

@@ -43,14 +43,14 @@ def _mesh_case(batches: int = 2) -> dict:
                                 np.random.default_rng(7), origin_zipf=1.0,
                                 same_district=0.6, hop_decay=0.5)
     ss, ts = ss.reshape(batches, BATCH), ts.reshape(batches, BATCH)
-    # B is far under the auto thresholds at this size: the system's own
-    # overrides pin what the auto-pick chooses at deployment size
-    system.shard_border, system.label_dtype = True, "uint16"
-    service = system.service(ServingPolicy(engine="auto"))
+    # B is far under the auto thresholds at this size: the policy pins
+    # what the auto-pick chooses at deployment size
+    service = system.service(ServingPolicy(engine="auto", shard_border=True,
+                                           label_dtype="uint16"))
     obs.reset()
     got = np.stack([service.submit(s, t).distances for s, t in zip(ss, ts)])
     counted = obs.counters()
-    engine = system.current_engine()
+    engine = system.current_engine(service.policy)
     layout = {"engine": type(engine).__name__,
               "border_sharded": engine.data.border_sharded,
               "dtype": str(engine.data.quant.dtype),

@@ -6,7 +6,7 @@ join parity — under ``hypothesis`` when available, over a seeded
 parametrization otherwise (same convention as test_core_properties).
 
 Engine layer: every serving layout (replicated, district-sharded,
-B-sharded, scatter-gather) must answer bit-for-bit identically in
+B-sharded) must answer bit-for-bit identically in
 uint16 and float32 on mixed-rule batches; the 8-device case re-runs the
 same builder in a subprocess with XLA_FLAGS (pattern from
 test_sharded_oracle).
@@ -156,12 +156,11 @@ def test_dtype_name_and_registry():
 # -- serving layouts: uint16 == float32 bit-for-bit -------------------------
 
 def _layout_case():
-    """All four layouts x {float32, uint16} on one mixed-rule batch.
+    """All three layouts x {float32, uint16} on one mixed-rule batch.
     Shared by the in-process (1-device) test and the 8-device
     subprocess."""
     from repro.edge import (BatchedQueryEngine, EdgeSystem,
                             ShardedBatchedEngine)
-    from repro.edge.scatter_gather import ScatterGatherPlane
     from repro.ingest import synthetic_continent
 
     # integral weights (U{1..15}) so the fitted spec is lossless and the
@@ -184,12 +183,10 @@ def _layout_case():
         shard = ShardedBatchedEngine(*args, quant=quant)
         bshard = ShardedBatchedEngine(*args, shard_border=True,
                                       quant=quant)
-        sg = ScatterGatherPlane.from_system(system, quant=quant)
         out[tag] = {
             "rep": np.asarray(rep.query(ss, ts)),
             "shard": np.asarray(shard.query(ss, ts)),
             "bshard": np.asarray(bshard.query(ss, ts)),
-            "sg": np.asarray(sg.execute(ss, ts)),
         }
         out["bytes"][tag] = {
             "rep": rep.size_bytes(),
@@ -244,17 +241,21 @@ def test_serving_policy_label_dtype_validation():
 
 def test_auto_dtype_small_system_stays_float32():
     """Auto never changes an answer: below the byte threshold the
-    resolved quant is None, so existing float32 tests stay bitwise
-    identical."""
+    layout stores float32 and the resolved quant is None, so existing
+    float32 tests stay bitwise identical."""
     from repro.core import bfs_grow_partition, grid_road_network
     from repro.edge import EdgeSystem
+    from repro.serve.service import ServingPolicy
     g = grid_road_network(6, 6, seed=0)
     part = bfs_grow_partition(g, 4, seed=0)
     system = EdgeSystem.deploy(g, part)
-    assert system._resolve_quant(None) is None
-    assert system._resolve_quant("auto") is None
+    assert system.layout().label_dtype == "float32"
+    assert system.layout(ServingPolicy(label_dtype="auto")) \
+        .label_dtype == "float32"
     assert system._resolve_quant("float32") is None
-    spec = system._resolve_quant("uint16")    # explicit: always honored
+    explicit = system.layout(ServingPolicy(label_dtype="uint16"))
+    assert explicit.label_dtype == "uint16"   # explicit: always honored
+    spec = system._resolve_quant(explicit.label_dtype)
     assert spec is not None and spec.dtype == np.dtype(np.uint16)
 
 
@@ -270,7 +271,7 @@ def test_service_explicit_uint16_matches_float32():
     ts = rng.integers(0, g.num_vertices, size=300)
     ref = system.service(ServingPolicy(label_dtype="float32")) \
         .submit(ss, ts).distances
-    for placement in ("replicated", "sharded", "scatter_gather"):
+    for placement in ("replicated", "sharded"):
         got = system.service(ServingPolicy(engine=placement,
                                            label_dtype="uint16")) \
             .submit(ss, ts).distances

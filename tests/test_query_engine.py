@@ -65,6 +65,7 @@ def test_rebuild_window_batched_certified_and_exact():
     sys_ = EdgeSystem.deploy(g, part)
     svc = sys_.service()
     rng = np.random.default_rng(2)
+    old = svc.plan(np.array([0]), np.array([1])).plane   # pre-window engine
     w2 = perturb_weights(g, rng, lo=0.8, hi=1.3)
     # simulate mid-window: locals refreshed + center rebuilt, shortcuts
     # NOT yet pushed → the batch must go through the Theorem-3 kernels
@@ -87,7 +88,7 @@ def test_rebuild_window_batched_certified_and_exact():
     # the default policy); once every server is fresh again the
     # steady-state engine must agree with the loop
     plan2 = svc.plan(ss, ts)
-    assert not plan2.window
+    assert not plan2.window and plan2.plane is not old
     np.testing.assert_array_equal(plan2.execute().distances,
                                   sys_.query_loop(ss, ts))
 

@@ -271,8 +271,7 @@ def _mesh8_case():
     ts = rng.integers(0, g.num_vertices, size=384)
     loop = sys_.query_loop(ss, ts)
     for pol in (ServingPolicy(), ServingPolicy(engine="replicated"),
-                ServingPolicy(engine="sharded", shard_border=True),
-                ServingPolicy(engine="scatter_gather")):
+                ServingPolicy(engine="sharded", shard_border=True)):
         np.testing.assert_array_equal(
             sys_.service(pol).submit(ss, ts).distances, loop)
     w2 = perturb_weights(g, np.random.default_rng(5), lo=0.8, hi=1.3)

@@ -354,16 +354,27 @@ def test_engine_layouts_bitwise_after_incremental_update(grid):
     g, part = grid
     sys_ = EdgeSystem.deploy(g, part, builder="jax")
     rng = np.random.default_rng(13)
-    w2 = scenario_weights("rush_hour", g, part, rng, 0.05)
-    sys_.apply_traffic_update(w2, incremental=True)
     ss = rng.integers(0, g.num_vertices, size=256)
     ts = rng.integers(0, g.num_vertices, size=256)
-    ref = sys_.query_loop(ss, ts)
     from repro.serve import ServingPolicy
-    for engine, border in (("replicated", None), ("sharded", False),
-                           ("sharded", True)):
-        svc = sys_.service(ServingPolicy(engine=engine, shard_border=border))
+    services = [sys_.service(ServingPolicy(engine=engine,
+                                           shard_border=border))
+                for engine, border in (("replicated", None),
+                                       ("sharded", False),
+                                       ("sharded", True))]
+    before = sys_.query_loop(ss, ts)
+    old = []
+    for svc in services:           # each service serves the old version
+        np.testing.assert_array_equal(svc.submit(ss, ts).distances, before)
+        old.append(svc.plan(ss, ts).plane)
+    w2 = scenario_weights("rush_hour", g, part, rng, 0.05)
+    sys_.apply_traffic_update(w2, incremental=True)
+    ref = sys_.query_loop(ss, ts)
+    assert not np.array_equal(ref, before)
+    for svc, engine in zip(services, old):
+        # the same service swaps to the new version's engine
         np.testing.assert_array_equal(svc.submit(ss, ts).distances, ref)
+        assert svc.plan(ss, ts).plane is not engine
 
 
 def test_service_forwards_client_districts_and_kernels(grid):
